@@ -106,7 +106,7 @@ def _bench_train_step_backends() -> list[str]:
     """The unified train step end-to-end, reference vs Pallas-fused rule
     backend (the fused kernels on their actual hot path, not only as
     isolated ops). Interpret mode on CPU: structure cost only."""
-    from repro.compat import use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.ps import CommitConfig, UpdateRules, make_train_step
 
     def quad_loss(params, batch):
@@ -120,11 +120,11 @@ def _bench_train_step_backends() -> list[str]:
     mbs = (jnp.stack([x, x]), jnp.stack([y, y]))
     params = {"w": jnp.asarray(rng.normal(size=(dim, 1)) * 0.1, jnp.float32)}
     cfg = CommitConfig(tau=2, local_lr=0.05, worker_axes=("data",))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     tau = jnp.asarray([2], jnp.int32)
 
     out = []
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for backend in ("reference", "fused"):
             step_fn = make_train_step(
                 quad_loss, cfg, UpdateRules(backend=backend), mesh=mesh)

@@ -92,7 +92,7 @@ def _overlap_row() -> list[str]:
 
     from repro.cluster import ADSP, ClusterEngine
     from repro.cluster.mesh_backend import MeshBackend, MeshTask
-    from repro.compat import use_mesh
+    from repro.launch.mesh import make_mesh
 
     from .common import time_fn
 
@@ -114,14 +114,14 @@ def _overlap_row() -> list[str]:
         make_microbatches=lambda r, tau, n: (jnp.stack([x] * tau),
                                              jnp.stack([y] * tau)),
     )
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     walls, params = {}, {}
     for name, overlap in (("mono", False), ("overlap", True)):
         backend = MeshBackend(task, mesh, tau=2, codec="bf16", n_shards=2,
                               fused_commit=True, overlap_shards=overlap)
         ClusterEngine(ADSP(search=False, gamma=4.0), backend)
         assert backend.fused_commit and backend.overlap_shards == overlap
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             walls[name] = time_fn(backend.run_round, iters=5, warmup=2)
         params[name] = backend.state.params
     match = all(

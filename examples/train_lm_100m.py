@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.compat import use_mesh
 from repro.data.synthetic import lm_tokens
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.ps import (
@@ -70,7 +70,7 @@ def main():
           f"rules={args.local_rule}+{args.commit_rule}, codec={codec.name}, "
           f"ps_shards={args.ps_shards}")
 
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     ccfg = CommitConfig(tau=args.tau, local_lr=args.local_lr, global_lr=1.0,
                         worker_axes=("data",), n_shards=args.ps_shards)
 
@@ -84,7 +84,7 @@ def main():
     tau_arr = jnp.full((len(jax.devices()),), args.tau, jnp.int32)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(args.steps):
             toks = lm_tokens(args.seed, i * 65537, args.tau * args.batch,
                              args.seq, cfg.vocab_size)[:, :-1]

@@ -32,7 +32,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.control import theory
 from repro.control.theory import WorkerProfile
@@ -146,10 +146,10 @@ class MeshBackend:
         self.fused_commit = step.fused_commit
         # the round's state is dead the moment the new one lands: donate
         # it so params/commit/transport buffers are updated in place.
-        # Donated buffers are consumed — init from a private copy of the
-        # params so the caller's init_params tree stays valid.
+        # Donated buffers are consumed — the state is built by its own jit,
+        # into fresh buffers, so the caller's init_params tree stays valid.
         self.step_fn = jax.jit(step, donate_argnums=step.donate_argnums)
-        self.state = step.init(jax.tree.map(jnp.array, task.init_params))
+        self.state = self._init_state(step, task.init_params, worker_axes)
         # effective shard count: the plan clamps to the leaf count, and
         # the state's version vector is the ground truth for what ran
         versions = jax.tree.leaves(self.state.shard_versions)
@@ -184,6 +184,23 @@ class MeshBackend:
         if self.fleet is not None:
             for w in self.workers:
                 self.fleet.join(w.index, 0.0, w.profile)
+
+    def _init_state(self, step, params, worker_axes) -> AdspState:
+        """The initial state, built in the layout the step returns it:
+        per-worker slots one per worker — each device materializes only
+        its own slot, never all of them — and everything else replicated.
+        A state built anywhere else would change the step's input
+        shardings after the first round and recompile it."""
+        rep = NamedSharding(self.mesh, P())
+        per_worker = (NamedSharding(self.mesh, P(tuple(worker_axes)))
+                      if worker_axes else rep)
+        abstract = jax.eval_shape(step.init, params)
+        shardings = dataclasses.replace(
+            jax.tree.map(lambda _: rep, abstract),
+            local_state=jax.tree.map(lambda _: per_worker, abstract.local_state),
+            transport_state=jax.tree.map(lambda _: per_worker,
+                                         abstract.transport_state))
+        return jax.jit(step.init, out_shardings=shardings)(params)
 
     # ------------------------------------------------------- overlapped commit
     def _init_overlap(self, step, ccfg, explicit_momentum: float) -> None:

@@ -37,7 +37,7 @@ def _quantize_kernel(e_ref, s_ref, q_ref, r_ref):
     r_ref[...] = e_ref[...] - q * scale
 
 
-def quantize_int8(e: jax.Array, scale: jax.Array, *, interpret: bool = True):
+def quantize_int8(e: jax.Array, scale: jax.Array, *, interpret: bool):
     """(R, C) f32 → (int8 payload, f32 error-feedback residual).
 
     ``scale`` is a (1, 1) f32 (positive; the caller guards zero) broadcast
@@ -69,7 +69,7 @@ def _dequantize_kernel(q_ref, s_ref, o_ref):
     o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0]
 
 
-def dequantize_int8(q: jax.Array, scale: jax.Array, *, interpret: bool = True):
+def dequantize_int8(q: jax.Array, scale: jax.Array, *, interpret: bool):
     """(R, C) int8 payload → f32 (the PS-side decode pass)."""
     blk = QBLOCK
     r, c = q.shape
@@ -93,7 +93,7 @@ def _encode_bf16_kernel(e_ref, q_ref, r_ref):
     r_ref[...] = e_ref[...] - q.astype(jnp.float32)
 
 
-def encode_bf16(e: jax.Array, *, interpret: bool = True):
+def encode_bf16(e: jax.Array, *, interpret: bool):
     """(R, C) f32 → (bf16 payload, f32 residual) in one pass."""
     blk = QBLOCK
     r, c = e.shape

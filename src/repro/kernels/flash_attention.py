@@ -86,7 +86,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B, S, Hq, D); k, v: (B, S, Hkv, D) → (B, S, Hq, D).
 
     S must be a multiple of the block sizes (ops.py pads + re-masks)."""

@@ -76,7 +76,7 @@ def _quantize_ef_kernel(u_ref, r_ref, s_ref, q_ref, ro_ref):
 
 
 def quantize_int8_ef(u: jax.Array, r: jax.Array, scale: jax.Array, *,
-                     interpret: bool = True):
+                     interpret: bool):
     """(R, C) f32 update + residual → (int8 payload, next residual) with
     the error-feedback add folded into the quantize pass."""
     return pl.pallas_call(
@@ -99,7 +99,7 @@ def _encode_bf16_ef_kernel(u_ref, r_ref, q_ref, ro_ref):
     ro_ref[...] = e - q.astype(jnp.float32)
 
 
-def encode_bf16_ef(u: jax.Array, r: jax.Array, *, interpret: bool = True):
+def encode_bf16_ef(u: jax.Array, r: jax.Array, *, interpret: bool):
     """(R, C) f32 update + residual → (bf16 payload, next residual)."""
     return pl.pallas_call(
         _encode_bf16_ef_kernel,
@@ -127,7 +127,7 @@ def _int8_apply_kernel(w_ref, d_ref, q_ref, s_ref, hp_ref, w_out, d_out):
     w_out[...] = w_ref[...] + delta
 
 
-def int8_decode_apply(w, prev_delta, q, scale, hp, *, interpret: bool = True):
+def int8_decode_apply(w, prev_delta, q, scale, hp, *, interpret: bool):
     """δ ← μ·δ − η·(q·s) ; W ← W + δ in one pass. ``hp`` is a (1, 2) f32
     [momentum, global_lr] operand; ``scale`` the per-leaf (1, 1) f32."""
     return pl.pallas_call(
@@ -152,7 +152,7 @@ def _bf16_apply_kernel(w_ref, d_ref, q_ref, hp_ref, w_out, d_out):
     w_out[...] = w_ref[...] + delta
 
 
-def bf16_decode_apply(w, prev_delta, q, hp, *, interpret: bool = True):
+def bf16_decode_apply(w, prev_delta, q, hp, *, interpret: bool):
     """Same single pass with the bf16-payload decode (a widening cast)."""
     return pl.pallas_call(
         _bf16_apply_kernel,
@@ -173,7 +173,7 @@ def _int8_accum_kernel(w_ref, q_ref, s_ref, hp_ref, w_out):
     w_out[...] = (w_ref[...] - lr.astype(u.dtype) * u).astype(w_ref.dtype)
 
 
-def int8_decode_accum(w, q, scale, hp, *, interpret: bool = True):
+def int8_decode_accum(w, q, scale, hp, *, interpret: bool):
     """Stateless plain-average pull: W ← W − η·(q·s) in one pass."""
     return pl.pallas_call(
         _int8_accum_kernel,
@@ -191,7 +191,7 @@ def _bf16_accum_kernel(w_ref, q_ref, hp_ref, w_out):
     w_out[...] = (w_ref[...] - lr.astype(u.dtype) * u).astype(w_ref.dtype)
 
 
-def bf16_decode_accum(w, q, hp, *, interpret: bool = True):
+def bf16_decode_accum(w, q, hp, *, interpret: bool):
     """Stateless plain-average pull for bf16 payloads."""
     return pl.pallas_call(
         _bf16_accum_kernel,

@@ -38,11 +38,12 @@ def _accum_kernel(u_ref, g_ref, lr_ref, o_ref):
     o_ref[...] = u_ref[...] + lr_ref[0, 0].astype(u_ref.dtype) * g_ref[...]
 
 
-def accumulate(u: jax.Array, g: jax.Array, local_lr, *, interpret: bool = True):
+def accumulate(u: jax.Array, g: jax.Array, local_lr, *, interpret: bool):
     blk = block_for(u.dtype)
     r, c = u.shape
     grid = (r // blk[0], c // blk[1])
-    lr = jnp.full((1, 1), local_lr, u.dtype)
+    # f32 operand: Mosaic extracts only 32-bit scalars from a vector
+    lr = jnp.full((1, 1), local_lr, jnp.float32)
     return pl.pallas_call(
         _accum_kernel,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
@@ -65,7 +66,7 @@ def _ps_apply_kernel(w_ref, d_ref, u_ref, hp_ref, w_out, d_out):
     w_out[...] = w_ref[...] + delta
 
 
-def ps_apply(w, prev_delta, u, global_lr, momentum, *, interpret: bool = True):
+def ps_apply(w, prev_delta, u, global_lr, momentum, *, interpret: bool):
     """Returns (new_w, new_delta); all (R, C) aligned like `accumulate`."""
     blk = block_for(w.dtype)
     r, c = w.shape

@@ -1,16 +1,16 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles padding to block multiples, dtype plumbing, pytree dispatch for
-the commit ops, and the interpret-mode switch: ``interpret=None`` (the
-default) auto-selects interpret=True unless a TPU backend is present, so
-the same call sites work in the CPU container (validation) and on real
-hardware (performance).
+the commit ops, and the interpret-mode switch. ``_interp`` is the one
+place it is resolved: ``interpret=None`` (the default) means interpret
+mode exactly when no TPU backend is present, so the same call sites run
+in the CPU container (validation) and natively on the chip; asking for
+interpret mode on a TPU is an error, never a silent slow path.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -40,35 +40,24 @@ __all__ = [
     "default_interpret",
 ]
 
-_TRUTHY = frozenset(("1", "true", "yes", "on"))
-_FALSY = frozenset(("0", "false", "no", "off"))
-
 
 @functools.lru_cache(maxsize=None)
 def default_interpret() -> bool:
     """Interpret-mode default for every Pallas wrapper (and the rule
-    registry in ``repro.ps``): the REPRO_PALLAS_INTERPRET env var wins
-    when set (1/true/yes/on or 0/false/no/off), else interpret unless a
-    TPU backend is present. Cached — the backend probe and getenv run
-    once per process, not once per wrapper call (call
-    ``default_interpret.cache_clear()`` after changing the env var)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if env in _TRUTHY:
-        return True
-    if env in _FALSY:
-        return False
-    if env:
-        raise ValueError(
-            f"REPRO_PALLAS_INTERPRET={env!r}: want one of "
-            f"{sorted(_TRUTHY)} / {sorted(_FALSY)}"
-        )
+    registry in ``repro.ps``): interpret unless a TPU backend is present.
+    Cached — the backend probe runs once per process, not once per
+    wrapper trace."""
     return jax.default_backend() != "tpu"
 
 
 def _interp(interpret):
-    if interpret is not None:
-        return interpret
-    return default_interpret()
+    if interpret is None:
+        return default_interpret()
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError(
+            "Pallas interpret mode was requested on a TPU backend; the "
+            "kernels compile natively there (pass interpret=None)")
+    return interpret
 
 
 def _pad_to(x, axis, mult):

@@ -35,24 +35,19 @@ def _rglru_kernel(a_ref, b_ref, o_ref, h_ref, *, block_s):
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0]  # (block_s, block_w)
-    b = b_ref[0]
-    h = h_ref[0]  # (block_w,)
+    # rows are read and written through the refs: a dynamic index on a
+    # loaded value (a[t]) has no Mosaic lowering, a dynamic ref slice does
+    def step(t, h):  # h: (1, block_w)
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h
+        return h
 
-    def step(t, carry):
-        h, out = carry
-        h = a[t] * h + b[t]
-        out = jax.lax.dynamic_update_index_in_dim(out, h, t, 0)
-        return h, out
-
-    out0 = jnp.zeros_like(a)
-    h, out = jax.lax.fori_loop(0, block_s, step, (h, out0))
-    h_ref[0] = h
-    o_ref[0] = out
+    h_ref[...] = jax.lax.fori_loop(0, block_s, step, h_ref[...])
 
 
 def rglru_scan(a, b, *, block_w: int = 1024, block_s: int = 256,
-               interpret: bool = True):
+               interpret: bool):
     """a, b: (B, S, W) float32 → h: (B, S, W). S % block_s == 0 and
     W % block_w == 0 (ops.py pads W; padding channels scan harmlessly)."""
     bsz, s, w = a.shape

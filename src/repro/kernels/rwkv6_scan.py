@@ -29,41 +29,47 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["rwkv6_scan"]
 
 
+def _column(row, eye):
+    """(1, N) row → (N, 1) column as a masked lane reduction (a small
+    transpose the VPU/XLU lowers for any N)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, stout_ref, st_ref,
                 *, block_s, n_s):
+    hi = pl.program_id(1)
     sj = pl.program_id(2)
 
     @pl.when(sj == 0)
     def _init():
         st_ref[...] = jnp.zeros_like(st_ref)
 
-    r = r_ref[0, 0]  # (block_s, N)
-    k = k_ref[0, 0]
-    v = v_ref[0, 0]
-    w = w_ref[0, 0]
-    u = u_ref[...]  # (1, N) bonus — .T below gives the (N, 1) key-axis column
+    n = st_ref.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    u_col = _column(u_ref[pl.ds(hi, 1), :], eye)  # (N, 1) bonus, key axis
 
-    def step(t, carry):
-        st, out = carry  # st: (N, N)
-        kt = k[t][:, None]  # (N, 1)
-        vt = v[t][None, :]  # (1, N)
-        kv = kt * vt  # (N, N)
-        ot = r[t] @ (st + u.T * kv)  # (N,)
-        st = w[t][:, None] * st + kv
-        out = jax.lax.dynamic_update_index_in_dim(out, ot, t, 0)
-        return st, out
+    # per-token rows come through dynamic ref slices (Mosaic has no
+    # lowering for a dynamic index on a loaded value); the key-axis
+    # operands become (N, 1) columns so every update is an (N, N) VPU op
+    def step(t, st):  # st: (N, N), key axis × value axis
+        row = pl.ds(t, 1)
+        k_col = _column(k_ref[0, 0, row, :], eye)
+        r_col = _column(r_ref[0, 0, row, :], eye)
+        w_col = _column(w_ref[0, 0, row, :], eye)
+        kv = k_col * v_ref[0, 0, row, :]  # (N, N) outer product k_tᵀ v_t
+        o_ref[0, 0, row, :] = jnp.sum(r_col * (st + u_col * kv), axis=0,
+                                      keepdims=True)
+        return w_col * st + kv
 
-    out0 = jnp.zeros_like(v)
-    st, out = jax.lax.fori_loop(0, block_s, step, (st_ref[...], out0))
-    st_ref[...] = st
-    o_ref[0, 0] = out
+    st_ref[...] = jax.lax.fori_loop(0, block_s, step, st_ref[...])
 
     @pl.when(sj == n_s - 1)
     def _emit_state():
         stout_ref[0, 0] = st_ref[...]
 
 
-def rwkv6_scan(r, k, v, w, bonus, *, block_s: int = 256, interpret: bool = True):
+def rwkv6_scan(r, k, v, w, bonus, *, block_s: int = 256, interpret: bool):
     """r,k,v,w: (B, S, H, N) (w float32 decay); bonus: (H, N).
 
     Returns (out (B, S, H, N) float32, final_state (B, H, N, N) float32).
@@ -91,7 +97,7 @@ def rwkv6_scan(r, k, v, w, bonus, *, block_s: int = 256, interpret: bool = True)
             pl.BlockSpec((1, 1, block_s, n), lambda bi, hi, sj: (bi, hi, sj, 0)),
             pl.BlockSpec((1, 1, block_s, n), lambda bi, hi, sj: (bi, hi, sj, 0)),
             pl.BlockSpec((1, 1, block_s, n), lambda bi, hi, sj: (bi, hi, sj, 0)),
-            pl.BlockSpec((1, n), lambda bi, hi, sj: (hi, 0)),
+            pl.BlockSpec((h, n), lambda bi, hi, sj: (0, 0)),  # whole bonus
         ],
         out_specs=(
             pl.BlockSpec((1, 1, block_s, n), lambda bi, hi, sj: (bi, hi, sj, 0)),

@@ -26,7 +26,6 @@ import traceback
 import jax
 
 from repro.configs import ARCH_IDS, get_config, get_smoke
-from repro.compat import SCAN_IN_PARTIAL_AUTO_BROKEN, use_mesh
 from repro.launch import specs as S
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build
@@ -52,17 +51,8 @@ def run_one(arch: str, shape: str, mesh_name: str, tau: int = 4,
     mesh = make_production_mesh(multi_pod=(mesh_name == "multi"))
     n_chips = mesh.devices.size
     overrides = dict(overrides or {})
-    if (spec.kind == "train" and SCAN_IN_PARTIAL_AUTO_BROKEN
-            and not overrides.get("granularity")):
-        # This jax's SPMD partitioner aborts on lax.scan inside a partially
-        # manual shard_map (see repro.compat); the layer-group scans make
-        # worker-axis train steps uncompilable, so measure the accum
-        # (no-worker-axis) variant and say so in the artifact.
-        overrides["granularity"] = "accum"
-        note = (note + "; " if note else "") + \
-            "worker-axis step not compilable on this jax: accum fallback"
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build(cfg, mesh, shape, tau=tau, attn_impl=attn_impl,
                        **overrides)
         lowered = bundle.lower()

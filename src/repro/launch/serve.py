@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke
 from repro.data.synthetic import lm_tokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 
 
@@ -101,7 +102,7 @@ def run_oneshot(args) -> dict:
             rng.normal(size=(args.batch, cfg.encoder.num_frames, cfg.encoder.d_model)) * 0.02,
             jnp.float32)
 
-    params = lm.lm_init(jax.random.PRNGKey(args.seed), cfg)
+    params = lm.lm_init_cast(jax.random.PRNGKey(args.seed), cfg)
     prefill = jax.jit(lambda p_, b: lm.lm_prefill(
         cfg, p_, b, reserve=args.new_tokens + 1))
     decode = jax.jit(lambda p_, t, c: lm.lm_decode_step(cfg, p_, t, c))
@@ -162,7 +163,7 @@ def run_engine(args) -> dict:
                              make_trace)
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
-    params = lm.lm_init(jax.random.PRNGKey(args.seed), cfg)
+    params = lm.lm_init_cast(jax.random.PRNGKey(args.seed), cfg)
     tc = TraceConfig(n_requests=args.requests, rate=args.rate,
                      slo_ms=args.slo_ms, seed=args.seed)
     trace = make_trace(args.trace, tc)
@@ -191,13 +192,14 @@ def run_engine(args) -> dict:
                                 metrics=sink, make_sync=make_sync, tick=tick)
         balance = balancer.run()
         report = balance.merged
-        synced_params = balancer.engines[0].params
+        engines = balancer.engines
     else:
         engine = ServeEngine(cfg, params, serve_cfg, trace, metrics=sink,
                              sync=make_sync(0) if make_sync else None,
                              tick=tick)
         report = engine.run()
-        synced_params = engine.params
+        engines = [engine]
+    synced_params = engines[0].params
     wall = time.time() - t0
     if args.track_training:
         loss_last = trainer.eval_loss(synced_params)
@@ -231,11 +233,13 @@ def run_engine(args) -> dict:
     if args.metrics:
         print(f"# metrics -> {args.metrics}")
     return {"report": report, "loss_first": loss_first, "loss_last": loss_last,
-            "trainer": trainer, "balance": balance}
+            "trainer": trainer, "balance": balance, "engines": engines,
+            "trace": trace}
 
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.trace:
         return run_engine(args)
     return run_oneshot(args)

@@ -26,6 +26,7 @@ monolithic PS, bit-identical to the unsharded stack.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -37,17 +38,18 @@ from repro.cluster import ADSP, ClusterEngine
 from repro.control import reward_model_names
 from repro.cluster.mesh_backend import MeshBackend, MeshTask
 from repro.configs import get_config, get_smoke
-from repro.compat import use_mesh
 from repro.control.theory import WorkerProfile
 from repro.data.synthetic import lm_tokens
 from repro.fleet import FleetConfig, JsonlSink, LeaseConfig, scheduler_names
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh, worker_axes_for
 from repro.models import lm
 from repro.models.attention import resolve_attn_impl
 from repro.models.config import ModelConfig
 from repro.ps import UpdateRules, add_rule_args, add_shard_args, rules_from_args
 from repro.transport import add_codec_args, codec_from_args
 
-__all__ = ["build_mesh_task", "make_trainer", "main"]
+__all__ = ["build_mesh_task", "cut_layers", "make_trainer", "unmet_requests", "main"]
 
 
 def build_mesh_task(cfg: ModelConfig, rules, *, seq: int, batch: int,
@@ -77,6 +79,19 @@ def build_mesh_task(cfg: ModelConfig, rules, *, seq: int, batch: int,
     )
 
 
+def cut_layers(cfg: ModelConfig, n: int) -> ModelConfig:
+    """``cfg`` cut to its first ``n`` layers, every width kept: the depth
+    cut that fits a published model on fewer chips (the layers left out
+    would sit on further pipeline stages). At least one whole period of
+    the layer pattern stays, so every block kind is present."""
+    period = len(cfg.layer_pattern)
+    if not period <= n <= cfg.num_layers:
+        raise ValueError(
+            f"--layers {n}: {cfg.name} needs {period}..{cfg.num_layers} "
+            "layers (at least one whole period of its layer pattern)")
+    return dataclasses.replace(cfg, num_layers=n)
+
+
 def make_trainer(cfg: ModelConfig, mesh, *, tau: int, seq: int, batch: int,
                  local_lr: float, global_lr: float, seed: int = 0,
                  gamma_rounds: float = 8.0, search_every: int = 0,
@@ -94,20 +109,19 @@ def make_trainer(cfg: ModelConfig, mesh, *, tau: int, seq: int, batch: int,
                  metrics=None,
                  ) -> tuple[MeshBackend, ClusterEngine, ADSP]:
     """Build the (backend, engine, policy) triple for an arch on a mesh."""
-    from repro.launch.mesh import worker_axes_for
     from repro.launch.steps import _rules_for
 
     worker_axes = worker_axes_for(cfg.adsp_granularity, mesh)
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    n_workers = int(np.prod([sizes[a] for a in worker_axes])) if worker_axes else 1
+    if batch % n_workers:
+        raise ValueError(
+            f"batch {batch} does not split over {n_workers} ADSP workers: "
+            "each worker takes batch/workers sequences per microstep")
     rules = _rules_for(mesh, worker_axes)
     task = build_mesh_task(cfg, rules, seq=seq, batch=batch, seed=seed,
                            attn_impl=attn_impl)
-    params = lm.lm_init(jax.random.PRNGKey(seed), cfg)
-    task.init_params = jax.tree.map(
-        lambda x: x.astype(jnp.dtype(cfg.dtype))
-        if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
-
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    n_workers = int(np.prod([sizes[a] for a in worker_axes])) if worker_axes else 1
+    task.init_params = lm.lm_init_cast(jax.random.PRNGKey(seed), cfg)
     speeds = speeds if speeds is not None else [1.0] * n_workers
     profiles = [WorkerProfile(v=float(v), o=0.0) for v in speeds]
     backend = MeshBackend(
@@ -117,6 +131,9 @@ def make_trainer(cfg: ModelConfig, mesh, *, tau: int, seq: int, batch: int,
         fused_commit=fused_commit, overlap_shards=overlap_shards,
         fleet=fleet, metrics=metrics,
     )
+    # the backend's state holds the params now; keep only their shapes so
+    # a second full copy of the model does not stay resident
+    task.init_params = jax.eval_shape(lambda: task.init_params)
     # drift mode stays armed even with no epoch cadence configured: the
     # detector, not the epoch clock, decides when to search
     policy = ADSP(
@@ -130,10 +147,39 @@ def make_trainer(cfg: ModelConfig, mesh, *, tau: int, seq: int, batch: int,
     return backend, engine, policy
 
 
+def unmet_requests(backend: MeshBackend, *, rule_backend: str | None,
+                   codec_backend: str | None,
+                   fused_commit: bool) -> list[str]:
+    """What was explicitly asked for but resolved to something else.
+
+    The library falls back where a fused implementation is missing
+    (``repro.ps.rules``, ``repro.transport.codec``, the chain commit);
+    an entry point may not. Only ``auto`` may choose."""
+    out = []
+    lr_rule, cr_rule = backend.rules
+    if rule_backend == "fused" and "reference" in (lr_rule.backend,
+                                                   cr_rule.backend):
+        out.append(f"--rule-backend fused resolved to "
+                   f"{lr_rule.name}[{lr_rule.backend}] + "
+                   f"{cr_rule.name}[{cr_rule.backend}]")
+    codec = backend.codec
+    if codec_backend == "fused" and (codec is None or codec.backend != "fused"):
+        name = codec.name if codec is not None else "none"
+        out.append(f"--codec-backend fused: codec {name!r} has no fused "
+                   "implementation")
+    if fused_commit and not backend.fused_commit:
+        out.append("--fused-commit did not take effect: it needs --codec "
+                   "int8|bf16, one worker and float32 commits")
+    return out
+
+
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true")
+    p.add_argument("--layers", type=int, default=0,
+                   help="keep only the first N layers at published widths "
+                        "(a depth cut, printed as a reduction; 0 = all)")
     p.add_argument("--steps", type=int, default=50)
     p.add_argument("--seq", type=int, default=128)
     p.add_argument("--batch", type=int, default=8)
@@ -167,8 +213,8 @@ def main(argv=None):
                         "to this path; summarize with tools/fleet_report.py")
     p.add_argument("--fused-commit", action="store_true",
                    help="single-pass decode+apply PS commit (DESIGN.md "
-                        "§16); needs --codec int8|bf16, falls back to the "
-                        "chain path where the fusion is not bit-exact")
+                        "§16); needs --codec int8|bf16, one worker and "
+                        "float32 commits, and exits with an error otherwise")
     p.add_argument("--overlap-shards", action="store_true",
                    help="with --fused-commit and --ps-shards K>1: issue "
                         "per-shard pull/decode dispatches back-to-back "
@@ -184,9 +230,15 @@ def main(argv=None):
     add_shard_args(p)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    full_layers = cfg.num_layers
+    if args.layers:
+        cfg = cut_layers(cfg, args.layers)
+    # one ADSP worker per device and no tensor parallelism: a Pallas
+    # kernel compiles only under a shard_map manual over every mesh axis
     n = len(jax.devices())
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_mesh((n,), ("data",))
     rules = rules_from_args(args)
     codec = codec_from_args(args)
     fleet = None
@@ -208,7 +260,15 @@ def main(argv=None):
         search_mode=args.search_mode, drift_threshold=args.drift_threshold,
         reward_model=args.reward_model, fleet=fleet, metrics=metrics,
     )
+    unmet = unmet_requests(backend, rule_backend=args.rule_backend,
+                           codec_backend=args.codec_backend,
+                           fused_commit=args.fused_commit)
+    if unmet:
+        p.error("; ".join(unmet))
     lr_rule, cr_rule = backend.rules
+    if args.layers:
+        print(f"# reduced: layers {cfg.num_layers} of {full_layers}, "
+              "every width as published")
     print(f"# arch={cfg.name} params={cfg.total_params()/1e6:.1f}M "
           f"workers={len(backend.workers)} tau={args.tau} "
           f"rules={lr_rule.name}+{cr_rule.name}[{cr_rule.backend}] "
@@ -225,7 +285,7 @@ def main(argv=None):
             print(f"step {rnd - 1:4d} loss {loss:.4f} "
                   f"({(time.time() - t0) / rnd:.2f}s/commit)")
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         backend.train(args.steps, check_period=policy.gamma,
                       epoch_rounds=args.search_every, on_round=on_round)
     print(f"# bytes_to_ps={backend.bytes_to_ps/1e6:.2f} MB "

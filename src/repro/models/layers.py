@@ -50,31 +50,38 @@ def default_rules(model_axis: str = "model", data_axis: str | None = None) -> di
 
 
 def annotate(x: jax.Array, logical: Sequence[str | None], rules: Mapping) -> jax.Array:
-    """with_sharding_constraint by logical axes; divisibility-guarded."""
+    """with_sharding_constraint by logical axes; divisibility-guarded.
+
+    Without an ambient mesh (plain CPU tests, single-device serving) there
+    is nothing to constrain against and ``x`` passes through; under one,
+    a constraint the mesh rejects raises."""
     if not rules:
+        return x
+    sizes = _ambient_mesh_axes()
+    if not sizes:
         return x
     spec = []
     for dim, name in zip(x.shape, logical):
         axis = rules.get(name) if name else None
-        spec.append(axis if axis and dim % _axis_size(axis) == 0 else None)
+        spec.append(axis if axis and dim % _axis_size(axis, sizes) == 0 else None)
     if all(s is None for s in spec):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except (RuntimeError, ValueError):
-        return x  # no ambient mesh (plain CPU tests)
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
-def _axis_size(axis) -> int:
-    from repro.compat import ambient_mesh_axes
+def _ambient_mesh_axes() -> dict[str, int]:
+    """Axis name -> size of the ambient mesh; {} when none is set."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or m.empty:
+        return {}
+    return dict(zip(m.axis_names, m.axis_sizes))
 
-    sizes = ambient_mesh_axes()
-    if not sizes:
-        return 1 << 30  # force "not divisible" → no constraint
+
+def _axis_size(axis, sizes: Mapping[str, int]) -> int:
     names = axis if isinstance(axis, tuple) else (axis,)
     n = 1
     for a in names:
-        n *= sizes.get(a, 1 << 30)
+        n *= sizes.get(a, 1 << 30)  # absent axis: never divisible
     return n
 
 

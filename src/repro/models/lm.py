@@ -48,6 +48,7 @@ from .rwkv6 import (
 
 __all__ = [
     "lm_init",
+    "lm_init_cast",
     "lm_loss",
     "lm_logits",
     "lm_prefill",
@@ -145,6 +146,20 @@ def lm_init(rng, cfg: ModelConfig):
         if e.d_model != cfg.d_model:
             params["enc_proj"] = dense_init(jax.random.fold_in(ks[7], 1), e.d_model, cfg.d_model)
     return params
+
+
+def lm_init_cast(rng, cfg: ModelConfig):
+    """``lm_init`` with every float leaf cast to ``cfg.dtype`` inside one
+    jit, so the float32 draw of each leaf is fused into its cast and a
+    whole float32 copy of the model is never resident on the device."""
+
+    def init(key):
+        dt = dtype_of(cfg)
+        return jax.tree.map(
+            lambda x: x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+            lm_init(key, cfg))
+
+    return jax.jit(init)(rng)
 
 
 # ---------------------------------------------------------------------------

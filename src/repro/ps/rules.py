@@ -11,9 +11,8 @@ factors into two independently pluggable pieces:
 
 Each (rule, backend) pair is registered here; ``backend`` is either
 ``"reference"`` (pure-JAX, the correctness contract) or ``"fused"``
-(single-HBM-pass Pallas kernels from ``repro.kernels``, with automatic
-interpret fallback off-TPU — see ``kernels.ops.default_interpret`` and
-the ``REPRO_PALLAS_INTERPRET`` env override). ``resolve_backend`` maps
+(single-HBM-pass Pallas kernels from ``repro.kernels``, in interpret
+mode off-TPU — see ``kernels.ops.default_interpret``). ``resolve_backend`` maps
 the default ``"auto"`` to fused on TPU and reference elsewhere, and a
 fused request for a rule with no fused implementation falls back to its
 reference implementation.
@@ -181,7 +180,7 @@ class UpdateRules:
 
     backend: 'reference' | 'fused' | None/'auto' (fused on TPU only).
     interpret: Pallas interpret override for fused kernels; None defers
-      to the auto probe + REPRO_PALLAS_INTERPRET (kernels.ops).
+      to the backend probe (kernels.ops).
     local_hp / commit_hp: extra hyperparameters forwarded to the rule
       factories (e.g. {'lr': 1e-3} for adamw).
     """

@@ -38,8 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SCAN_IN_PARTIAL_AUTO_BROKEN, shard_map as _compat_shard_map
-
 from .fused_codec import FUSABLE_CODECS, fused_commit_name
 from .rules import LocalRule, UpdateRules, get_commit_rule
 from .sharding import ShardPlan
@@ -136,7 +134,6 @@ def make_local_update(
     local_rule: LocalRule,
     *,
     remat: bool = False,
-    unroll=1,
 ) -> Callable:
     """The τ-microstep local-update scan: the per-worker inner loop.
 
@@ -167,7 +164,6 @@ def make_local_update(
         idxs = jnp.arange(ccfg.tau, dtype=jnp.int32)
         (_, u, ls), losses = jax.lax.scan(
             body, (params, zeros, local_state), (microbatches, idxs),
-            unroll=unroll,
         )
         denom = jnp.maximum(tau_i.astype(jnp.float32), 1.0)
         return u, ls, jnp.sum(losses) / denom
@@ -329,10 +325,7 @@ def make_train_step(
         return state.shard_versions + 1
 
     if axes:
-        # On the 0.4.x series XLA aborts on a lax.scan inside a partially
-        # manual shard_map; the scan is static-length, so unroll there.
-        unroll = True if SCAN_IN_PARTIAL_AUTO_BROKEN else 1
-        run = make_local_update(loss_fn, ccfg, local_rule, remat=remat, unroll=unroll)
+        run = make_local_update(loss_fn, ccfg, local_rule, remat=remat)
         if batch_spec is None:
             batch_spec = P(None, axes if len(axes) > 1 else axes[0])
 
@@ -386,13 +379,13 @@ def make_train_step(
         # sharding is handled by auto GSPMD outside the manual set.
         rep = P()
         wspec = _axes_spec(axes)
-        sharded = _compat_shard_map(
+        sharded = jax.shard_map(
             _sharded_body,
-            mesh,
+            mesh=mesh,
             in_specs=(rep, rep, wspec, wspec, rep, batch_spec, wspec),
             out_specs=(rep, rep, wspec, wspec, rep, rep),
             axis_names=set(axes),
-            check=False,
+            check_vma=False,
         )
 
         def train_step(state: AdspState, microbatches, tau_per_worker):
@@ -404,7 +397,7 @@ def make_train_step(
             return AdspState(p, c, l, s, t, _next_versions(state)), loss
 
     else:
-        run = make_local_update(loss_fn, ccfg, local_rule, remat=remat, unroll=1)
+        run = make_local_update(loss_fn, ccfg, local_rule, remat=remat)
 
         def train_step(state: AdspState, microbatches, tau_per_worker):
             _validate_state(state)

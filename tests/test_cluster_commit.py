@@ -11,7 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import use_mesh
 from repro.ps import (
     AdspState,
     CommitConfig,
@@ -60,7 +59,7 @@ def test_adsp_step_tau1_equals_sgd(problem):
     params, (x, y) = problem
     cfg = CommitConfig(tau=1, local_lr=0.1, global_lr=1.0, worker_axes=("data",))
     mesh = _mesh1()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_adsp_step(quad_loss, cfg, mesh, batch_spec=jax.sharding.PartitionSpec(None, "data"))
         state = AdspState.create(params)
         mb = (x[None], y[None])  # tau leading dim
@@ -77,7 +76,7 @@ def test_adsp_step_masking(problem):
     params, (x, y) = problem
     cfg = CommitConfig(tau=3, local_lr=0.1, global_lr=1.0, worker_axes=("data",))
     mesh = _mesh1()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_adsp_step(quad_loss, cfg, mesh, batch_spec=jax.sharding.PartitionSpec(None, "data"))
         mb = (jnp.stack([x, x, x]), jnp.stack([y, y, y]))
         s1, _ = step(AdspState.create(params), mb, jnp.asarray([1], jnp.int32))
@@ -96,7 +95,7 @@ def test_accum_step_matches_adsp_single_worker(problem):
     cfg = CommitConfig(tau=2, local_lr=0.05, global_lr=1.0, worker_axes=("data",))
     mesh = _mesh1()
     mb = (jnp.stack([x, x]), jnp.stack([y, y]))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         adsp = make_adsp_step(quad_loss, cfg, mesh, batch_spec=jax.sharding.PartitionSpec(None, "data"))
         s_a, loss_a = adsp(AdspState.create(params), mb, jnp.asarray([2], jnp.int32))
     accum = make_accum_step(quad_loss, cfg)
@@ -111,7 +110,7 @@ def test_adsp_step_converges(problem):
     params, (x, y) = problem
     cfg = CommitConfig(tau=4, local_lr=0.05, global_lr=1.0, worker_axes=("data",))
     mesh = _mesh1()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_adsp_step(quad_loss, cfg, mesh, batch_spec=jax.sharding.PartitionSpec(None, "data"))
         state = AdspState.create(params)
         mb = (jnp.broadcast_to(x, (4, *x.shape)), jnp.broadcast_to(y, (4, *y.shape)))

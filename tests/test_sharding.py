@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 from numpy.testing import assert_array_equal
 
-from repro.compat import use_mesh
 from repro.control.theory import WorkerProfile
 from repro.cluster import make_policy
 from repro.edgesim import SimConfig, Simulator
@@ -143,7 +142,7 @@ def _run(problem, granularity, n_shards, rounds=4, commit="momentum_delta"):
         quad_loss, cfg, UpdateRules(commit=commit, backend="reference"),
         mesh=mesh, granularity=granularity, explicit_momentum=0.3,
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         for _ in range(rounds):
             state, loss = jax.jit(step)(state, mbs, jnp.asarray([2], jnp.int32))
@@ -194,7 +193,7 @@ def test_single_leaf_model_clamps_to_monolithic():
     cfg = CommitConfig(tau=1, local_lr=0.1, n_shards=4)
     step = make_train_step(loss, cfg, UpdateRules(backend="reference"),
                            mesh=mesh, granularity="data")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         assert state.shard_versions == ()
         state, _ = jax.jit(step)(state, (jnp.stack([x]), jnp.stack([y])),
@@ -209,7 +208,7 @@ def test_stale_state_without_versions_raises(problem):
     mbs = (jnp.stack([batch[0]]), jnp.stack([batch[1]]))
     step = make_train_step(quad_loss, cfg, UpdateRules(backend="reference"),
                            mesh=mesh, granularity="data")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="shard_versions"):
             step(AdspState.create(params), mbs, jnp.ones((1,), jnp.int32))
 
@@ -352,7 +351,7 @@ def test_mesh_backend_sharded_state():
     for k in (1, 2):
         backend = MeshBackend(task, mesh, tau=2, n_shards=k)
         ClusterEngine(ADSP(search=False, gamma=4.0), backend)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             backend.train(rounds=3)
         outs[k] = backend
     assert outs[2].n_shards == 2
@@ -378,7 +377,7 @@ def _run_fused(problem, n_shards, codec, fused, rounds=4):
         mesh=mesh, granularity="data", explicit_momentum=0.3,
         codec=codec, fused_commit=fused,
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         for _ in range(rounds):
             state, loss = jax.jit(step)(state, mbs, jnp.asarray([2], jnp.int32))
@@ -477,7 +476,7 @@ def test_mesh_backend_overlapped_shards_bit_identical():
     for name, kw in variants.items():
         backend = MeshBackend(task, mesh, tau=2, codec="bf16", n_shards=2, **kw)
         ClusterEngine(ADSP(search=False, gamma=4.0), backend)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             losses = [backend.run_round() for _ in range(3)]
         outs[name] = (backend, losses)
     assert not outs["chain"][0].fused_commit

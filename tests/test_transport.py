@@ -17,7 +17,6 @@ import numpy as np
 import pytest
 from numpy.testing import assert_allclose, assert_array_equal
 
-from repro.compat import use_mesh
 from repro.control.theory import WorkerProfile
 from repro.edgesim import SimConfig, Simulator
 from repro.edgesim.profiles import ratio_profiles, with_links
@@ -248,7 +247,7 @@ def test_overlapped_shard_pulls_donate_param_buffers():
     backend = MeshBackend(task, mesh, tau=2, codec="bf16", n_shards=2,
                           fused_commit=True, overlap_shards=True)
     ClusterEngine(ADSP(search=False, gamma=4.0), backend)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         backend.run_round()  # warm the push/pull jits (first call compiles)
         before = {leaf.unsafe_buffer_pointer()
                   for leaf in jax.tree.leaves(backend.state.params)}
@@ -302,7 +301,7 @@ def _run_steps(problem, codec, rounds=4, backend="reference"):
     mbs = (jnp.stack([batch[0]] * 2), jnp.stack([batch[1]] * 2))
     step = make_train_step(quad_loss, cfg, UpdateRules(backend="reference"),
                            mesh=mesh, codec=codec)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         for _ in range(rounds):
             state, loss = jax.jit(step)(state, mbs, jnp.asarray([2], jnp.int32))
@@ -331,7 +330,7 @@ def test_train_step_fused_codec_matches_reference(problem):
     for backend in ("reference", "fused"):
         step = make_train_step(quad_loss, cfg, UpdateRules(backend="reference"),
                                mesh=mesh, codec=get_codec("int8", backend=backend))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = step.init(params)
             for _ in range(3):
                 state, loss = jax.jit(step)(state, mbs, jnp.asarray([2], jnp.int32))
@@ -347,7 +346,7 @@ def test_transport_state_mismatch_raises(problem):
     mbs = (jnp.stack([batch[0]]), jnp.stack([batch[1]]))
     step = make_train_step(quad_loss, cfg, UpdateRules(backend="reference"),
                            mesh=mesh, codec="int8")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="transport_state does not match"):
             step(AdspState.create(params), mbs, jnp.ones((1,), jnp.int32))
 
@@ -457,7 +456,7 @@ def test_mesh_backend_codec_bytes_accounting():
     mesh = jax.make_mesh((1,), ("data",))
     backend = MeshBackend(task, mesh, tau=2, codec="int8")
     ClusterEngine(ADSP(search=False, gamma=4.0), backend)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         backend.train(rounds=3)
     assert backend.codec.name == "int8"
     assert backend.bytes_per_round == backend.codec.encoded_nbytes(task.init_params)

@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 from numpy.testing import assert_allclose, assert_array_equal
 
-from repro.compat import use_mesh
 from repro.ps import (
     AdspState,
     CommitConfig,
@@ -58,7 +57,7 @@ def _stack(batch, tau):
     return jnp.stack([x] * tau), jnp.stack([y] * tau)
 
 
-def _seed_local_update_fn(loss_fn, cfg, unroll):
+def _seed_local_update_fn(loss_fn, cfg):
     """Verbatim seed implementation (core.commit.make_local_update_fn at
     PR 1) — the bit-for-bit oracle for the sgd LocalRule."""
     grad_fn = jax.value_and_grad(loss_fn)
@@ -81,7 +80,7 @@ def _seed_local_update_fn(loss_fn, cfg, unroll):
 
         idxs = jnp.arange(cfg.tau, dtype=jnp.int32)
         (_, u), losses = jax.lax.scan(
-            body, (params, zeros), (microbatches, idxs), unroll=unroll
+            body, (params, zeros), (microbatches, idxs)
         )
         denom = jnp.maximum(tau_i.astype(jnp.float32), 1.0)
         return u, jnp.sum(losses) / denom
@@ -91,12 +90,7 @@ def _seed_local_update_fn(loss_fn, cfg, unroll):
 
 def _seed_adsp_step(loss_fn, cfg, mesh, batch_spec, explicit_momentum=0.0):
     """Verbatim seed implementation (core.commit.make_adsp_step at PR 1)."""
-    from repro.compat import SCAN_IN_PARTIAL_AUTO_BROKEN
-    from repro.compat import shard_map as compat_shard_map
-
-    local_update = _seed_local_update_fn(
-        loss_fn, cfg, unroll=True if SCAN_IN_PARTIAL_AUTO_BROKEN else 1
-    )
+    local_update = _seed_local_update_fn(loss_fn, cfg)
     axes = cfg.worker_axes
 
     def _sharded_body(params, prev_delta, step, microbatches, tau_per_worker):
@@ -115,11 +109,11 @@ def _seed_adsp_step(loss_fn, cfg, mesh, batch_spec, explicit_momentum=0.0):
 
     rep = jax.sharding.PartitionSpec()
     tau_spec = jax.sharding.PartitionSpec(axes if len(axes) > 1 else axes[0])
-    sharded = compat_shard_map(
-        _sharded_body, mesh,
+    sharded = jax.shard_map(
+        _sharded_body, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec, tau_spec),
         out_specs=(rep, rep, rep, rep),
-        axis_names=set(axes), check=False,
+        axis_names=set(axes), check_vma=False,
     )
 
     def adsp_step(params, prev_delta, step, microbatches, tau_per_worker):
@@ -194,7 +188,7 @@ def test_train_step_matches_seed_arithmetic(problem, granularity):
             quad_loss, _dc.replace(cfg, worker_axes=()), explicit_momentum=mu
         ))
         tau_seed = jnp.asarray(tau_i, jnp.int32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         p, d, s = params, jax.tree.map(jnp.zeros_like, params), jnp.zeros((), jnp.int32)
         for _ in range(3):
@@ -221,7 +215,7 @@ def test_legacy_state_and_scalar_tau_still_accepted(problem):
                             CommitConfig(tau=2, local_lr=0.05, global_lr=1.0,
                                          worker_axes=()),
                             UpdateRules(backend="reference"))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         s_direct, l_direct = direct(direct.init(params), mbs, tau)
         s_legacy, l_legacy = direct(AdspState.create(params), mbs, tau)
         # legacy scalar tau_active still accepted by the accum path
@@ -250,7 +244,7 @@ def test_fused_backend_matches_reference_from_train_step(problem, granularity):
                                mesh=mesh, granularity=granularity,
                                explicit_momentum=0.5)
         assert step.rules[1].backend == backend
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = step.init(params)
             for _ in range(3):
                 state, loss = jax.jit(step)(state, mbs, tau)
@@ -314,7 +308,7 @@ def test_plain_average_is_worker_mean(problem):
         quad_loss, cfg,
         UpdateRules(commit="plain_average", backend="reference"), mesh=mesh,
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state, _ = jax.jit(step)(step.init(params), mbs, jnp.ones((1,), jnp.int32))
     _, g = jax.value_and_grad(quad_loss)(params, batch)
     expect = params["w"] - 0.1 * g["w"]
@@ -332,7 +326,7 @@ def test_adamw_state_masking(problem):
     step = make_train_step(quad_loss, cfg,
                            UpdateRules(local="adamw", backend="reference"),
                            mesh=mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         state, _ = jax.jit(step)(state, mbs, jnp.asarray([1], jnp.int32))
         assert int(state.local_state.step[0]) == 1
@@ -351,7 +345,7 @@ def test_adamw_at_worker_converges(problem):
         UpdateRules(local="adamw", backend="reference", local_hp={"lr": 0.05}),
         mesh=mesh,
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         losses = []
         for _ in range(30):
@@ -371,7 +365,7 @@ def test_sgd_momentum_local_rule_converges(problem):
                     local_hp={"momentum": 0.8}),
         mesh=mesh,
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = step.init(params)
         losses = []
         for _ in range(30):
@@ -381,30 +375,29 @@ def test_sgd_momentum_local_rule_converges(problem):
 
 
 def test_default_interpret_cached_and_env_override(monkeypatch):
-    """kernels.ops probes the backend once (cached) and honours the
-    REPRO_PALLAS_INTERPRET override."""
+    """kernels.ops probes the backend once (cached); the retired
+    REPRO_PALLAS_INTERPRET override no longer changes the resolution, and
+    interpret mode on a TPU backend (steered here) is an error."""
     from repro.kernels import ops
 
     try:
-        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
         ops.default_interpret.cache_clear()
         auto = ops.default_interpret()
         assert auto == (jax.default_backend() != "tpu")
-        assert ops._interp(None) is auto and ops._interp(True) is True
+        assert ops._interp(None) is auto and ops._interp(False) is False
 
         monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-        # cache still serves the old value until cleared...
+        ops.default_interpret.cache_clear()
         assert ops.default_interpret() is auto
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         ops.default_interpret.cache_clear()
         assert ops.default_interpret() is False
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "true")
-        ops.default_interpret.cache_clear()
-        assert ops.default_interpret() is True
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "sideways")
-        ops.default_interpret.cache_clear()
-        with pytest.raises(ValueError):
-            ops.default_interpret()
+        assert ops._interp(None) is False and ops._interp(False) is False
+        with pytest.raises(ValueError, match="interpret mode"):
+            ops._interp(True)
     finally:
+        monkeypatch.undo()
         ops.default_interpret.cache_clear()
 
 
@@ -440,7 +433,7 @@ def test_mismatched_state_raises_clearly(problem):
     step = make_train_step(quad_loss, cfg,
                            UpdateRules(local="adamw", backend="reference"),
                            mesh=mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="local_state does not match"):
             step(AdspState.create(params), mbs, jnp.ones((1,), jnp.int32))
 
@@ -515,7 +508,7 @@ def test_fused_commit_bit_identical_to_chain(problem, codec, commit,
                                granularity=granularity, codec=codec,
                                explicit_momentum=0.5, fused_commit=fused)
         assert step.fused_commit is (fused and codec in ("int8", "bf16"))
-        with use_mesh(mesh) if mesh is not None else _null_ctx():
+        with jax.set_mesh(mesh) if mesh is not None else _null_ctx():
             state = step.init(params)
             for _ in range(3):
                 state, loss = jax.jit(step)(state, mbs, tau)
