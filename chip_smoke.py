@@ -12,18 +12,17 @@ Phases (each failing phase makes the exit code non-zero):
     ``repro.launch.train.make_trainer`` and ``MeshBackend.train``: seq
     2048, batch 1, τ=2, 3 commit rounds on the fast path (flash
     attention, fused sgd, int8 codec, fused decode+apply commit).
-    Reports compile seconds, wall seconds per round, the Pallas calls in
-    the compiled step and peak device memory; checks finite losses and
-    compares the committed params after one round with the reference
-    chain (reference rules and codec, no fused commit) from the same
-    start on the same data.
+    Checks the fast path's Pallas calls in the compiled step and finite
+    losses, and compares the committed params after one round with the
+    reference chain (reference rules and codec, no fused commit) from
+    the same start on the same data.
   * serve — rwkv6-3b whole (32 layers) through ``repro.launch.serve``'s
     engine on an 8-request Poisson trace over 4 slots; every request must
     be answered. One request's tokens must equal the same request served
     alone by the same engine programs, and each must be the argmax, up to
     ``SERVE_LOGIT_TOL``, of the solo-decode oracle (the full forward
-    ``lm_prefill`` + ``lm_decode_step``) fed the served prefix. Reports
-    wall seconds of the engine's own prefill and pool-wide decode.
+    ``lm_prefill`` + ``lm_decode_step``) fed the served prefix, and the
+    engine's own prefill program, replayed, must give its first token.
   * four chips (``--four-chips`` only) — the same granite cut as 4 ADSP
     workers on a data=4 mesh with unequal speeds, so the per-worker τ_i
     differ. Checks the per-worker state and the params are spread over
@@ -34,7 +33,8 @@ Phases (each failing phase makes the exit code non-zero):
 
 The run stays in this one process: a chip belongs to one process at a
 time. The last line of standard output is one JSON object, printed only
-when every phase passed.
+when every phase passed. Times and device utilization come from the
+benchmark (``chipbench/run.py``), not from here.
 """
 
 from __future__ import annotations
@@ -46,7 +46,6 @@ import json
 import pathlib
 import re
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -84,13 +83,6 @@ def check(ok: bool, what: str) -> None:
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-def blocked_seconds(fn):
-    """Run ``fn`` and wait for every array it returns; (result, seconds)."""
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(fn())
-    return out, time.perf_counter() - t0
 
 
 # ---------------------------------------------------------------------------
@@ -208,13 +200,10 @@ def train_phase(cfg, mesh) -> None:
     with jax.set_mesh(mesh):
         mbs = backend.task.make_microbatches(0, t["tau"], 1)
         tau_arr = jnp.asarray(backend.tau_per_worker(), jnp.int32)
-        t0 = time.perf_counter()
         compiled = backend.step_fn.lower(backend.state, mbs, tau_arr).compile()
-        compile_s = time.perf_counter() - t0
     kernels = pallas_calls(compiled.as_text())
     mem = compiled.memory_analysis()
-    log(f"[train] compile {compile_s:.2f} s (set-up); tpu_custom_call "
-        f"x{sum(kernels.values())}: {kernels}")
+    log(f"[train] tpu_custom_call x{sum(kernels.values())}: {kernels}")
     log(f"[train] memory_analysis: arguments {mem.argument_size_in_bytes / 2**30:.2f} "
         f"GiB, temp {mem.temp_size_in_bytes / 2**30:.2f} GiB, aliased "
         f"{mem.alias_size_in_bytes / 2**30:.2f} GiB (the compiler's own HBM "
@@ -223,26 +212,16 @@ def train_phase(cfg, mesh) -> None:
     check(not missing, f"fast-path kernels missing from the compiled step: "
           f"{sorted(missing)}")
 
-    walls, after_first = [], {}
-    last = [time.perf_counter()]
+    after_first = {}
 
     def on_round(rnd, loss):
-        jax.block_until_ready(backend.state)
-        now = time.perf_counter()
-        walls.append(now - last[0])
         if rnd == 1:
             after_first["params"] = jax.device_get(backend.state.params)
-        last[0] = time.perf_counter()
 
     with jax.set_mesh(mesh):
-        last[0] = time.perf_counter()
         backend.train(t["rounds"], check_period=policy.gamma, on_round=on_round)
     losses = [l for _, l in backend.losses]
-    peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", -1)
-    log(f"[train] wall s/round {[round(w, 4) for w in walls]}; losses "
-        f"{[round(l, 4) for l in losses]}; peak_bytes_in_use {peak} "
-        f"({peak / 2**30:.2f} GiB; buffers only, the TPU runtime leaves a "
-        f"program's temporaries out of it)")
+    log(f"[train] losses {[round(l, 4) for l in losses]}")
     check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
     del backend, engine, policy, compiled
     release()
@@ -283,15 +262,12 @@ def serve_phase(serve_argv) -> None:
     from repro.serve import ServeEngine
 
     args = build_parser().parse_args(serve_argv)
-    t0 = time.perf_counter()
     out = run_engine(args)
-    wall = time.perf_counter() - t0
     report, engine, trace = out["report"], out["engines"][0], out["trace"]
     cfg = engine.cfg
     log(f"[serve] {cfg.name}: layers {cfg.num_layers} (whole), d_model "
         f"{cfg.d_model}, params {cfg.total_params() / 1e9:.3f} B, dtype "
-        f"{cfg.dtype}; engine wall {wall:.2f} s incl. compile, "
-        f"{report.decode_steps} decode steps")
+        f"{cfg.dtype}; {report.decode_steps} decode steps")
     served = {r.req for r in report.records}
     check(served == {r.rid for r in trace}
           and all(report.tokens_by_rid[r.rid] for r in trace),
@@ -328,24 +304,9 @@ def serve_phase(serve_argv) -> None:
     check(max(gaps) <= SERVE_LOGIT_TOL,
           f"a served token trails the oracle's best by {max(gaps):.4f} logits")
 
-    # the engine's own compiled programs, replayed now that they are
-    # compiled: the request's prefill bucket and the pool-wide decode step
-    (first, _), prefill_s = blocked_seconds(lambda: engine._prefill(req))
+    # the engine's own prefill program for the request's bucket, replayed
+    first, _ = engine._prefill(req)
     check(first == pooled[0], f"prefill replay gave {first}, served {pooled[0]}")
-    steps = req.max_new - 1
-
-    def decode_steps():
-        toks, caches = jnp.zeros((engine.pool.n_slots, 1), jnp.int32), engine.pool.caches
-        for _ in range(steps):
-            ids, caches = engine._decode(engine.params, toks, caches)
-            toks = ids[:, None]
-        return toks
-
-    _, decode_s = blocked_seconds(decode_steps)
-    log(f"[serve] engine programs: prefill of the {req.prompt_len}-token "
-        f"prompt {prefill_s:.4f} s; "
-        f"{steps} pool-wide decode steps ({engine.pool.n_slots} slots) "
-        f"{decode_s:.4f} s, {decode_s / steps * 1e3:.2f} ms/step")
 
 
 def one_slot_per_device(tree, n: int):
@@ -378,17 +339,15 @@ def four_chip_phase(cfg, mesh) -> None:
     local_rule, commit_rule = backend.rules
     codec, loss_fn = backend.codec, backend.task.loss_fn
 
-    t0 = time.perf_counter()
     with jax.set_mesh(mesh):
         backend.train(1)
         jax.block_until_ready(backend.state)
-    wall = time.perf_counter() - t0
     taus = [w.steps for w in backend.workers]
     st = backend.state
     spread = {"local_state": one_slot_per_device(st.local_state, n),
               "transport_state": one_slot_per_device(st.transport_state, n)}
     param_devs = {len(x.sharding.device_set) for x in jax.tree.leaves(st.params)}
-    log(f"[four] round 1 wall {wall:.2f} s incl. compile; tau_i {taus}; "
+    log(f"[four] round 1: tau_i {taus}; "
         f"loss {backend.losses[-1][1]:.4f}; one slot per device "
         f"{spread} (None: no leaves, sgd is stateless); param device_set "
         f"sizes {param_devs}")

@@ -75,6 +75,10 @@ def validate_records(records: Iterable) -> list[Violation]:
 
     for i, rec in enumerate(records):
         kind = getattr(rec, "kind", None)
+        if kind == "span":
+            # host-clock spans join the stream in bulk as a run ends,
+            # stamped with the virtual time they opened at: no ordering
+            continue
         t = float(getattr(rec, "t", 0.0))
         if t < last_t:
             out.append(Violation(

@@ -17,6 +17,13 @@ policy object (same Γ, same probe windows) drives this backend and the
 virtual-clock simulator. Checkpoint/epoch cadence is driven by
 ``train(..., check_period=, epoch_rounds=)``.
 
+Spans (``repro.fleet.metrics``, while its recorder is on): one
+``adsp.round`` per round (key: the round index; count: Σ τ_i) holding
+``adsp.data`` (the microbatches), ``adsp.dispatch`` (the step's call),
+``adsp.sync`` (the loss fetch that ends the round on the host) and
+``adsp.control`` (τ_i and the engine's commit handling); ``train``'s
+checkpoint and epoch calls are ``adsp.control`` too.
+
 Churn: mid-run SpeedChanged is fully supported (speeds only shape τ_i).
 WorkerJoined/WorkerLeft are rejected — the worker set is baked into the
 compiled SPMD program; elastic membership needs a recompile, which the
@@ -37,6 +44,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.control import theory
 from repro.control.theory import WorkerProfile
 from repro.fleet import CommitRecord, EvalRecord, FleetConfig, FleetMonitor
+from repro.fleet.metrics import span
 from repro.ps import (
     AdspState,
     CommitConfig,
@@ -327,33 +335,42 @@ class MeshBackend:
     def run_round(self) -> float:
         """One fused commit round; dispatches CommitApplied per worker."""
         self._ensure_started()
-        tau_arr = self.tau_per_worker()
-        mbs = self.task.make_microbatches(self._round, self.tau, len(self.workers))
-        if self.overlap_shards:
-            loss = self._commit_overlapped(mbs, tau_arr)
-        else:
-            self.state, loss = self.step_fn(
-                self.state, mbs, jnp.asarray(tau_arr, jnp.int32))
-        self._round += 1
-        self.now = self._round * self.round_seconds
-        self.bytes_to_ps += self.bytes_per_round
-        loss = float(loss)
-        self.losses.append((self.now, loss))
-        if self.metrics is not None:
-            self.metrics.record(EvalRecord(t=self.now, loss=loss))
-        for w, t in zip(self.workers, tau_arr):
-            w.steps += int(t)
-            w.steps_since_commit = 0
-            w.commits += 1
+        k = self._round
+        with span("adsp.round", k, t=self.now) as rnd:
+            with span("adsp.control", k, t=self.now):
+                tau_arr = self.tau_per_worker()
+            rnd.set(tau=int(tau_arr.sum()))
+            with span("adsp.data", k, t=self.now):
+                mbs = self.task.make_microbatches(k, self.tau, len(self.workers))
+            with span("adsp.dispatch", k, t=self.now):
+                if self.overlap_shards:
+                    loss = self._commit_overlapped(mbs, tau_arr)
+                else:
+                    self.state, loss = self.step_fn(
+                        self.state, mbs, jnp.asarray(tau_arr, jnp.int32))
+            self._round += 1
+            self.now = self._round * self.round_seconds
+            self.bytes_to_ps += self.bytes_per_round
+            with span("adsp.sync", k, t=self.now):
+                loss = float(loss)
+            self.losses.append((self.now, loss))
             if self.metrics is not None:
-                # one fused all-reduce round: latency is the round wall
-                # time; the pull is folded into the collective (0 bytes)
-                self.metrics.record(CommitRecord(
-                    t=self.now, worker=w.index, latency=self.round_seconds,
-                    push_bytes=float(self._per_worker_nbytes),
-                    pull_bytes=0.0, stale_shards=0, n_shards=self.n_shards,
-                ))
-            self.engine.commit_applied(w)
+                self.metrics.record(EvalRecord(t=self.now, loss=loss))
+            with span("adsp.control", k, t=self.now):
+                for w, t in zip(self.workers, tau_arr):
+                    w.steps += int(t)
+                    w.steps_since_commit = 0
+                    w.commits += 1
+                    if self.metrics is not None:
+                        # one fused all-reduce round: latency is the round
+                        # wall time; the pull is folded into the collective
+                        # (0 bytes)
+                        self.metrics.record(CommitRecord(
+                            t=self.now, worker=w.index, latency=self.round_seconds,
+                            push_bytes=float(self._per_worker_nbytes),
+                            pull_bytes=0.0, stale_shards=0, n_shards=self.n_shards,
+                        ))
+                    self.engine.commit_applied(w)
         return loss
 
     # ----------------------------------------------------------------- churn
@@ -390,12 +407,14 @@ class MeshBackend:
         done = 0
         while done < rounds:
             if epoch_rounds and done and done % epoch_rounds == 0:
-                self.engine.epoch_end()
+                with span("adsp.control", self._round, t=self.now):
+                    self.engine.epoch_end()
             loss = self.run_round()
             done += 1
             if on_round is not None:
                 on_round(done, loss)
             if self.now >= next_check:
-                self.engine.checkpoint()
+                with span("adsp.control", self._round - 1, t=self.now):
+                    self.engine.checkpoint()
                 next_check += check_period
         return self.losses

@@ -32,6 +32,7 @@ from .metrics import (
     PullRecord,
     SearchRecord,
     ServeRecord,
+    SpanRecord,
     from_dict,
     load_jsonl,
     record_kinds,
@@ -61,7 +62,7 @@ __all__ = [
     # metrics
     "MetricRecord", "CommitRecord", "EvalRecord", "SearchRecord",
     "DriftRecord", "LeaseRecord", "ChurnRecord", "CapabilityRecord",
-    "AssignRecord", "ServeRecord", "PullRecord",
+    "AssignRecord", "ServeRecord", "PullRecord", "SpanRecord",
     "MetricsSink", "MetricsLog", "JsonlSink",
     "record_kinds", "to_dict", "from_dict", "load_jsonl",
 ]

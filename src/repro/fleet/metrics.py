@@ -16,21 +16,39 @@ Sinks are anything with ``record(rec)``; ``MetricsLog`` keeps the stream
 in memory, ``JsonlSink`` appends to a file as the run executes. A ``None``
 sink everywhere means "don't record" — producers guard every emission so
 an uninstrumented run pays nothing.
+
+Spans (``SpanRecord``) are the one record kind on the host's wall clock:
+``span``/``event`` time host work where it happens (the serving engine's
+steps, the ADSP round loop) into a bounded process-wide log,
+``recorded_spans()``. The recorder is on only while a profiler session
+collects or a launcher's stream is open (``span_stream``); off, a span
+site costs one check. Each span also opens a profiler
+``TraceAnnotation`` of its name, so a trace shows it beside the device's
+operations, and compiles that run inside a span become ``compile``
+spans under it (from ``jax.monitoring``).
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import json
 import pathlib
+import time
 from typing import Iterable, Protocol, runtime_checkable
+
+import jax
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "MetricRecord", "CommitRecord", "EvalRecord", "SearchRecord",
     "DriftRecord", "LeaseRecord", "ChurnRecord", "CapabilityRecord",
-    "AssignRecord", "ServeRecord", "PullRecord",
+    "AssignRecord", "ServeRecord", "PullRecord", "SpanRecord",
     "MetricsSink", "MetricsLog", "JsonlSink",
     "record_kinds", "to_dict", "from_dict", "load_jsonl",
+    "recording", "span", "event", "stamp", "span_stream",
+    "recorded_spans", "clear_spans",
 ]
 
 
@@ -186,6 +204,29 @@ class PullRecord(MetricRecord):
     replica: int = 0
 
 
+@_register("span")
+@dataclasses.dataclass(frozen=True)
+class SpanRecord(MetricRecord):
+    """A stretch of host work on the wall clock: ``start_ns``/``end_ns``
+    from ``time.perf_counter_ns``. ``t`` stays the producer's virtual
+    time when the span opened, as on every other record. ``id`` is the
+    span's own, ``parent`` the id of the span open around it (None at
+    the top); spans of one request or round share ``key``; ``counts``
+    holds host-side values at hand (slots, tokens, the program)."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    id: int
+    parent: int | None = None
+    key: int | None = None
+    counts: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def duration_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+
 # ---------------------------------------------------------------------------
 # Serialization
 # ---------------------------------------------------------------------------
@@ -275,3 +316,171 @@ class JsonlSink:
 
     def __exit__(self, *exc):
         self.close()
+
+
+# ---------------------------------------------------------------------------
+# Span recorder
+# ---------------------------------------------------------------------------
+#
+# Process-wide on purpose: the spans of one process share one clock and
+# one parent chain however many engines or backends record them. Wall
+# clock readings happen here alone; nothing on a virtual clock reads
+# them back, so replay stays deterministic.
+
+SPAN_LOG_MAX = 1 << 18  # spans kept; the oldest go first
+_span_log: collections.deque = collections.deque(maxlen=SPAN_LOG_MAX)
+_open: list = []  # the spans open now, innermost last
+_last_id = 0
+_streams = 0  # span_stream blocks open
+_listening = False
+
+# jax.monitoring's compile-duration events, by compile stage
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+
+_profiling = TraceAnnotation.is_enabled
+
+
+def recording() -> bool:
+    """Whether span sites record: while a profiler session collects, or
+    while a ``span_stream`` is open."""
+    return _streams > 0 or _profiling()
+
+
+def _now_ns() -> int:
+    """The host clock spans are stamped on (observation only: the
+    virtual clocks never read it)."""
+    return time.perf_counter_ns()  # reprolint: ignore[wall-clock-in-sim]
+
+
+def _new_id() -> int:
+    global _last_id
+    _last_id += 1
+    return _last_id
+
+
+def _listen_compiles() -> None:
+    global _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None or not recording():
+        return
+    end = _now_ns()
+    up = _open[-1] if _open else None
+    _span_log.append(SpanRecord(
+        t=up.t if up else 0.0, name="compile", start_ns=end - int(duration * 1e9),
+        end_ns=end, id=_new_id(), parent=up.id if up else None,
+        counts={"stage": stage, "fun": str(kw.get("fun_name", ""))}))
+
+
+class _Span:
+    __slots__ = ("name", "key", "t", "counts", "id", "parent", "start_ns", "_ann")
+
+    def __init__(self, name: str, key, t: float, counts: dict):
+        self.name, self.key, self.t, self.counts = name, key, t, counts
+
+    def __enter__(self) -> "_Span":
+        self.parent = _open[-1].id if _open else None
+        self.id = _new_id()
+        _open.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.start_ns = _now_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = _now_ns()
+        self._ann.__exit__(*exc)
+        _open.pop()
+        _span_log.append(SpanRecord(
+            t=self.t, name=self.name, start_ns=self.start_ns, end_ns=end,
+            id=self.id, parent=self.parent, key=self.key, counts=self.counts))
+
+    def set(self, **counts) -> None:
+        """Add counts known only inside the span."""
+        self.counts.update(counts)
+
+
+class _Off:
+    """What a span site gets with the recorder off."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def set(self, **counts) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+def span(name: str, key: int | None = None, *, t: float = 0.0, **counts):
+    """Context manager timing the block as a span ``name`` (with the
+    producer's virtual time ``t``); inside it, ``.set(**counts)`` adds
+    counts. With the recorder off it records nothing."""
+    if not (_streams or _profiling()):  # recording(), inlined: the off path
+        return _OFF
+    _listen_compiles()
+    return _Span(name, key, t, counts)
+
+
+def stamp() -> int | None:
+    """The host clock in ns for a span that ``event`` closes later, or
+    None with the recorder off."""
+    return _now_ns() if recording() else None
+
+
+def event(name: str, start_ns: int, key: int | None = None, *, t: float = 0.0,
+          **counts) -> None:
+    """Record a span from ``start_ns`` (a ``stamp``) to now, under the span
+    open now. Its start is past, so it has no profiler twin."""
+    if not recording():
+        return
+    up = _open[-1].id if _open else None
+    _span_log.append(SpanRecord(
+        t=t, name=name, start_ns=start_ns,
+        end_ns=_now_ns(), id=_new_id(), parent=up, key=key, counts=counts))
+
+
+def recorded_spans() -> list[SpanRecord]:
+    """The spans recorded so far, in the order they ended."""
+    return list(_span_log)
+
+
+def clear_spans() -> None:
+    _span_log.clear()
+
+
+@contextlib.contextmanager
+def span_stream(sink: MetricsSink | None):
+    """Keep the recorder on while a launcher's metrics stream is open, and
+    append the spans recorded meanwhile to ``sink`` as the block ends.
+    ``sink`` None leaves the recorder as it was."""
+    global _streams
+    if sink is None:
+        yield
+        return
+    _listen_compiles()
+    first = _last_id
+    _streams += 1
+    try:
+        yield
+    finally:
+        _streams -= 1
+        for rec in list(_span_log):
+            if rec.id > first:
+                sink.record(rec)

@@ -158,6 +158,7 @@ def run_oneshot(args) -> dict:
 
 def run_engine(args) -> dict:
     from repro.fleet import JsonlSink, MetricsLog
+    from repro.fleet.metrics import span_stream
     from repro.serve import (LoadBalancer, ReplicaSync, ServeConfig,
                              ServeEngine, ShardedTrainer, TraceConfig,
                              make_trace)
@@ -186,19 +187,21 @@ def run_engine(args) -> dict:
     sink = JsonlSink(args.metrics) if args.metrics else MetricsLog()
     t0 = time.time()
     balance = None
-    if args.replicas > 1:
-        balancer = LoadBalancer(cfg, params, serve_cfg, trace,
-                                n_replicas=args.replicas, router=args.router,
-                                metrics=sink, make_sync=make_sync, tick=tick)
-        balance = balancer.run()
-        report = balance.merged
-        engines = balancer.engines
-    else:
-        engine = ServeEngine(cfg, params, serve_cfg, trace, metrics=sink,
-                             sync=make_sync(0) if make_sync else None,
-                             tick=tick)
-        report = engine.run()
-        engines = [engine]
+    # the engine's spans join the --metrics stream as the run ends
+    with span_stream(sink if args.metrics else None):
+        if args.replicas > 1:
+            balancer = LoadBalancer(cfg, params, serve_cfg, trace,
+                                    n_replicas=args.replicas, router=args.router,
+                                    metrics=sink, make_sync=make_sync, tick=tick)
+            balance = balancer.run()
+            report = balance.merged
+            engines = balancer.engines
+        else:
+            engine = ServeEngine(cfg, params, serve_cfg, trace, metrics=sink,
+                                 sync=make_sync(0) if make_sync else None,
+                                 tick=tick)
+            report = engine.run()
+            engines = [engine]
     synced_params = engines[0].params
     wall = time.time() - t0
     if args.track_training:

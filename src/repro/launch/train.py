@@ -41,6 +41,7 @@ from repro.configs import get_config, get_smoke
 from repro.control.theory import WorkerProfile
 from repro.data.synthetic import lm_tokens
 from repro.fleet import FleetConfig, JsonlSink, LeaseConfig, scheduler_names
+from repro.fleet.metrics import span_stream
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh, worker_axes_for
 from repro.models import lm
@@ -285,7 +286,8 @@ def main(argv=None):
             print(f"step {rnd - 1:4d} loss {loss:.4f} "
                   f"({(time.time() - t0) / rnd:.2f}s/commit)")
 
-    with jax.set_mesh(mesh):
+    # the round loop's spans join the --metrics stream as training ends
+    with jax.set_mesh(mesh), span_stream(metrics):
         backend.train(args.steps, check_period=policy.gamma,
                       epoch_rounds=args.search_every, on_round=on_round)
     print(f"# bytes_to_ps={backend.bytes_to_ps/1e6:.2f} MB "

@@ -53,6 +53,14 @@ The run loop is a stepping API (``submit``/``run_until``) so a
 ``serve.balance.LoadBalancer`` can drive N engines on one virtual clock;
 ``run()`` is the single-replica convenience that feeds the engine's own
 trace through it.
+
+While the span recorder of ``repro.fleet.metrics`` is on, the engine
+also times its host work on the wall clock: one ``serve.step`` span per
+action, with ``serve.prefill`` (host prompt build), ``serve.dispatch``
+(a jitted call), ``serve.fetch`` (waiting for token ids),
+``serve.insert``/``serve.evict`` inside it, and ``serve.admit`` from a
+request's ``submit`` to its slot or lane. The virtual clock never reads
+them.
 """
 
 from __future__ import annotations
@@ -66,7 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.synthetic import lm_tokens
-from repro.fleet.metrics import PullRecord, ServeRecord
+from repro.fleet.metrics import PullRecord, ServeRecord, event, span, stamp
 from repro.models import lm
 
 from .cache import CachePool
@@ -383,7 +391,7 @@ class ServeEngine:
             min(padded, _prev_pow2(self._ring_limit))
         nblk = (padded + step - 1) // step
 
-        def fn(params, toks, nv):
+        def prefill_bucket(params, toks, nv):
             caches = lm.init_decode_caches(self.cfg, 1, cap)
             lgs = []
             for j in range(nblk):
@@ -399,7 +407,7 @@ class ServeEngine:
             lg = jnp.stack(lgs)[jstar]  # (1, V)
             return jnp.argmax(lg, axis=-1).astype(jnp.int32), caches
 
-        return jax.jit(fn)
+        return jax.jit(prefill_bucket)
 
     # ------------------------------------------------------------ helpers
     def prompt_tokens(self, req: Request) -> np.ndarray:
@@ -416,11 +424,16 @@ class ServeEngine:
         if fn is None:
             fn = self._build_prefill_fn(padded)
             self._prefill_fns[padded] = fn
-        toks = np.zeros((1, padded), np.int32)
-        toks[:, : req.prompt_len] = self.prompt_tokens(req)
-        tok, caches = fn(self.params, jnp.asarray(toks),
-                         jnp.asarray([req.prompt_len], jnp.int32))
-        return int(tok[0]), caches
+        with span("serve.prefill", req.rid, t=self.t, valid=req.prompt_len,
+                  padded=padded):
+            toks = np.zeros((1, padded), np.int32)
+            toks[:, : req.prompt_len] = self.prompt_tokens(req)
+        with span("serve.dispatch", req.rid, t=self.t, program="prefill_bucket",
+                  padded=padded):
+            tok, caches = fn(self.params, jnp.asarray(toks),
+                             jnp.asarray([req.prompt_len], jnp.int32))
+        with span("serve.fetch", req.rid, t=self.t):
+            return int(tok[0]), caches
 
     def _version(self) -> int:
         return self.sync.version if self.sync is not None else 0
@@ -463,11 +476,24 @@ class ServeEngine:
         self._lanes: dict[int, _Lane] = {}
         self._prompt_np: dict[int, np.ndarray] = {}
         self._chunk_tok = None  # last chunk dispatch's device-side argmaxes
+        self._submit_ns: dict[int, int] = {}  # rid -> host stamp, recorder on
 
     def submit(self, req: Request) -> None:
         """Hand a request to the admission queue (arrival bookkeeping is
         the caller's: submit when the clock reaches ``req.arrival``)."""
         self._queue.append(req)
+        t0 = stamp()
+        if t0 is not None:
+            self._submit_ns[req.rid] = t0
+
+    def _admit(self) -> Request:
+        """Take the scheduler's pick off the queue; its ``serve.admit``
+        span ends here."""
+        req = self._queue.pop(self.scheduler.pick(self._queue, self.t))
+        t0 = self._submit_ns.pop(req.rid, None)
+        if t0 is not None:
+            event("serve.admit", t0, req.rid, t=self.t)
+        return req
 
     @property
     def has_work(self) -> bool:
@@ -527,11 +553,14 @@ class ServeEngine:
     # -------------------------------------------------------------- steps
     def _step(self) -> bool:
         """One timed action; False when nothing can run (idle)."""
-        if self.serve_cfg.prefill_chunk:
-            return self._step_chunked()
-        return self._step_monolithic()
+        with span("serve.step", t=self.t, slots=len(self._slots),
+                  queued=len(self._queue)) as sp:
+            action = (self._step_chunked() if self.serve_cfg.prefill_chunk
+                      else self._step_monolithic())
+            sp.set(action=action or "idle", has_work=int(self.has_work))
+        return action is not None
 
-    def _step_monolithic(self) -> bool:
+    def _step_monolithic(self) -> str | None:
         cfg = self.serve_cfg
         if cfg.mode == "static" and not self._slots and self._queue:
             self._filling = True
@@ -539,7 +568,7 @@ class ServeEngine:
                      (cfg.mode == "continuous" or self._filling))
 
         if self._queue and can_admit:
-            req = self._queue.pop(self.scheduler.pick(self._queue, self.t))
+            req = self._admit()
             t_admit = self.t
             first, caches = self._prefill(req)
             pf = cfg.cost.prefill(req.prompt_len)
@@ -551,29 +580,36 @@ class ServeEngine:
             if done_now:
                 self._complete(st, self.t, prefill_only=True)
             else:
-                slot = self.pool.insert(req.rid, caches)
+                with span("serve.insert", req.rid, t=self.t) as sp:
+                    slot = self.pool.insert(req.rid, caches)
+                    sp.set(slot=slot)
                 self._last_tok[slot] = first
                 self._slots[slot] = st
-            return True
+            return "prefill"
         self._filling = False
 
         if not self._slots:
-            return False
+            return None
         self._decode_step()
-        return True
+        return "decode"
 
-    def _step_chunked(self) -> bool:
+    def _step_chunked(self) -> str | None:
         # lane admission is zero-cost bookkeeping: the scheduler hands
         # queued requests to free lanes, then finished lanes drain into
         # free decode slots, then exactly one timed step runs. When both
         # kinds of work exist, the chunk rides the decode step (one
         # combined step: decode cost + the chunk's per-token work); a
         # standalone chunk (empty pool) pays its own dispatch base.
+        chunk = self.serve_cfg.prefill_chunk
         while self._queue and self.lanes.n_free > 0:
-            req = self._queue.pop(self.scheduler.pick(self._queue, self.t))
-            slot = self.lanes.admit(req.rid)
+            req = self._admit()
+            with span("serve.insert", req.rid, t=self.t, pool="lanes") as sp:
+                slot = self.lanes.admit(req.rid)
+                sp.set(slot=slot)
             self._lanes[slot] = _Lane(req=req, t_admit=self.t)
-            self._prompt_np[req.rid] = self.prompt_tokens(req)
+            with span("serve.prefill", req.rid, t=self.t, valid=req.prompt_len,
+                      padded=-(-req.prompt_len // chunk) * chunk):
+                self._prompt_np[req.rid] = self.prompt_tokens(req)
         self._drain_ready()
 
         chunk_work = any(l.first is None for l in self._lanes.values())
@@ -583,17 +619,17 @@ class ServeEngine:
             self._decode_step(piggyback_tokens=pend[2])
             self._chunk_finalize(pend)
             self._drain_ready()
-            return True
+            return "chunk+decode"
         if chunk_work:
             pend = self._chunk_issue()
             self.t += self.serve_cfg.cost.chunk(pend[2])
             self._chunk_finalize(pend)
             self._drain_ready()
-            return True
+            return "chunk"
         if decode_work:
             self._decode_step()
-            return True
-        return False
+            return "decode"
+        return None
 
     def _chunk_issue(self):
         """Dispatch one (ragged) chunk over every mid-prompt lane.
@@ -614,10 +650,11 @@ class ServeEngine:
             prompt = self._prompt_np[lane.req.rid]
             blk[slot, :n] = prompt[0, lane.consumed:lane.consumed + n]
             active.append(slot)
-        tok, self.lanes.caches = self._chunk_fn(
-            self.params, jnp.asarray(blk), self.lanes.caches,
-            jnp.asarray(start), jnp.asarray(nv),
-        )
+        with span("serve.dispatch", t=self.t, program="chunk", tokens=int(nv.sum())):
+            tok, self.lanes.caches = self._chunk_fn(
+                self.params, jnp.asarray(blk), self.lanes.caches,
+                jnp.asarray(start), jnp.asarray(nv),
+            )
         self._chunk_dispatches += 1
         self._chunk_tok = tok  # device array; fetched in finalize
         return active, nv, int(nv.sum())
@@ -625,7 +662,8 @@ class ServeEngine:
     def _chunk_finalize(self, pend) -> None:
         cfg = self.serve_cfg
         active, nv, _ = pend
-        tok_host = np.asarray(self._chunk_tok)
+        with span("serve.fetch", t=self.t):
+            tok_host = np.asarray(self._chunk_tok)
         for slot in active:
             lane = self._lanes[slot]
             lane.consumed += int(nv[slot])
@@ -644,7 +682,8 @@ class ServeEngine:
 
     def _free_lane(self, slot: int) -> None:
         lane = self._lanes.pop(slot)
-        self.lanes.evict(lane.req.rid)
+        with span("serve.evict", lane.req.rid, t=self.t, pool="lanes", slot=slot):
+            self.lanes.evict(lane.req.rid)
         del self._prompt_np[lane.req.rid]
 
     def _drain_ready(self) -> None:
@@ -655,8 +694,10 @@ class ServeEngine:
                 continue
             if self.pool.n_free == 0:
                 break
-            src = self.lanes.extract(lane.req.rid)
-            dslot = self.pool.insert(lane.req.rid, src)
+            with span("serve.insert", lane.req.rid, t=self.t) as sp:
+                src = self.lanes.extract(lane.req.rid)
+                dslot = self.pool.insert(lane.req.rid, src)
+                sp.set(slot=dslot)
             self._last_tok[dslot] = lane.first
             self._slots[dslot] = _Active(
                 req=lane.req, t_admit=lane.t_admit,
@@ -667,10 +708,11 @@ class ServeEngine:
 
     def _decode_step(self, piggyback_tokens: int = 0) -> None:
         cfg = self.serve_cfg
-        toks = jnp.asarray(self._last_tok[:, None])
-        tok_ids, self.pool.caches = self._decode(
-            self.params, toks, self.pool.caches
-        )
+        with span("serve.dispatch", t=self.t, program="decode"):
+            toks = jnp.asarray(self._last_tok[:, None])
+            tok_ids, self.pool.caches = self._decode(
+                self.params, toks, self.pool.caches
+            )
         self.t += cfg.cost.decode(cfg.slots)
         if piggyback_tokens:
             self.t += cfg.cost.piggyback(piggyback_tokens)
@@ -689,7 +731,8 @@ class ServeEngine:
                     replica=self.replica,
                 ))
 
-        next_tok = np.asarray(tok_ids)
+        with span("serve.fetch", t=self.t):
+            next_tok = np.asarray(tok_ids)
         for slot in sorted(self._slots):
             st = self._slots[slot]
             tok = int(next_tok[slot])
@@ -699,7 +742,8 @@ class ServeEngine:
             if (st.gen >= st.req.max_new or
                     (cfg.eos_id is not None and tok == cfg.eos_id)):
                 self._complete(st, self.t)
-                self.pool.evict(st.req.rid)
+                with span("serve.evict", st.req.rid, t=self.t, slot=slot):
+                    self.pool.evict(st.req.rid)
                 del self._slots[slot]
         if self.serve_cfg.prefill_chunk:
             self._drain_ready()

@@ -48,6 +48,7 @@ from repro.fleet import (
     PullRecord,
     SearchRecord,
     ServeRecord,
+    SpanRecord,
     from_dict,
     get_scheduler,
     load_jsonl,
@@ -373,6 +374,8 @@ SAMPLE_RECORDS = [
     ServeRecord(t=9.0, req=5, queue=0.01, prefill=0.004, decode=0.05,
                 total=0.064, tokens=9, slo=0.8, slo_ok=True, version=3),
     PullRecord(t=10.0, stale_shards=2, n_shards=4, nbytes=2048.0),
+    SpanRecord(t=10.0, name="serve.step", start_ns=5_000, end_ns=9_000, id=7,
+               parent=3, key=12, counts={"action": "decode", "slots": 4}),
 ]
 
 

@@ -435,7 +435,11 @@ def test_launcher_engine_mode(capsys, tmp_path):
     text = capsys.readouterr().out
     assert len(out["report"].records) == 5
     assert "SLO attainment" in text
-    assert len(load_jsonl(path)) == 5
+    recs = load_jsonl(path)
+    assert sum(r.kind == "serve" for r in recs) == 5
+    # the engine's host spans follow: one admission wait per request
+    admits = [r for r in recs if r.kind == "span" and r.name == "serve.admit"]
+    assert sorted(r.key for r in admits) == sorted(r.req for r in out["report"].records)
 
 
 # ---------------------------------------------------------------------------
