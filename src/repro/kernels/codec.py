@@ -11,58 +11,39 @@ into single HBM trips:
   * dequantize_int8: x ← q·s
   * encode_bf16:     q ← bf16(e) ; r ← e − f32(q)   (same single-pass shape)
 
-Arrays arrive as flattened 2-D buffers tiled into lane-aligned VMEM
-blocks; because the int8 payload participates, tiles are (32, 1024)
-(int8 min sublane count is 32; f32/bf16 operands are fine at any
-multiple of 8/16). The ops.py wrappers pad ragged tails and reshape;
-the per-leaf scale is a jnp reduction computed by the caller — only the
-elementwise passes live here.
+Each call runs over one leaf in its own shape and layout, tiled by
+``kernels.tiling``; with an int8 payload taking part, row bands are
+multiples of 32 sublanes. ``e`` is read in its own dtype and widened to
+f32 in-register. The per-leaf scale is a jnp reduction computed by the
+caller — only the elementwise passes live here.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-__all__ = ["quantize_int8", "dequantize_int8", "encode_bf16", "QBLOCK"]
+from .tiling import stream
 
-QBLOCK = (32, 1024)  # int8-safe sublane × lane-aligned VMEM tile
+__all__ = ["quantize_int8", "dequantize_int8", "encode_bf16"]
 
 
 def _quantize_kernel(e_ref, s_ref, q_ref, r_ref):
     scale = s_ref[0, 0]
-    q = jnp.clip(jnp.round(e_ref[...] / scale), -127.0, 127.0)
+    e = e_ref[...].astype(jnp.float32)
+    q = jnp.clip(jnp.round(e / scale), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
-    r_ref[...] = e_ref[...] - q * scale
+    r_ref[...] = e - q * scale
 
 
 def quantize_int8(e: jax.Array, scale: jax.Array, *, interpret: bool):
-    """(R, C) f32 → (int8 payload, f32 error-feedback residual).
+    """One leaf → (int8 payload, f32 error-feedback residual).
 
     ``scale`` is a (1, 1) f32 (positive; the caller guards zero) broadcast
     to every block like the fused-commit hyperparameter operands.
     """
-    blk = QBLOCK
-    r, c = e.shape
-    grid = (r // blk[0], c // blk[1])
-    return pl.pallas_call(
-        _quantize_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct(e.shape, jnp.int8),
-            jax.ShapeDtypeStruct(e.shape, jnp.float32),
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(blk, lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec(blk, lambda i, j: (i, j)),
-            pl.BlockSpec(blk, lambda i, j: (i, j)),
-        ),
-        interpret=interpret,
-    )(e, scale)
+    return stream(_quantize_kernel, (e,), (scale,), (jnp.int8, jnp.float32),
+                  interpret=interpret)
 
 
 def _dequantize_kernel(q_ref, s_ref, o_ref):
@@ -70,45 +51,19 @@ def _dequantize_kernel(q_ref, s_ref, o_ref):
 
 
 def dequantize_int8(q: jax.Array, scale: jax.Array, *, interpret: bool):
-    """(R, C) int8 payload → f32 (the PS-side decode pass)."""
-    blk = QBLOCK
-    r, c = q.shape
-    grid = (r // blk[0], c // blk[1])
-    return pl.pallas_call(
-        _dequantize_kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(blk, lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec(blk, lambda i, j: (i, j)),
-        interpret=interpret,
-    )(q, scale)
+    """One int8 payload leaf → f32 (the PS-side decode pass)."""
+    return stream(_dequantize_kernel, (q,), (scale,), (jnp.float32,),
+                  interpret=interpret)[0]
 
 
 def _encode_bf16_kernel(e_ref, q_ref, r_ref):
-    q = e_ref[...].astype(jnp.bfloat16)
+    e = e_ref[...].astype(jnp.float32)
+    q = e.astype(jnp.bfloat16)
     q_ref[...] = q
-    r_ref[...] = e_ref[...] - q.astype(jnp.float32)
+    r_ref[...] = e - q.astype(jnp.float32)
 
 
 def encode_bf16(e: jax.Array, *, interpret: bool):
-    """(R, C) f32 → (bf16 payload, f32 residual) in one pass."""
-    blk = QBLOCK
-    r, c = e.shape
-    grid = (r // blk[0], c // blk[1])
-    return pl.pallas_call(
-        _encode_bf16_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct(e.shape, jnp.bfloat16),
-            jax.ShapeDtypeStruct(e.shape, jnp.float32),
-        ),
-        grid=grid,
-        in_specs=[pl.BlockSpec(blk, lambda i, j: (i, j))],
-        out_specs=(
-            pl.BlockSpec(blk, lambda i, j: (i, j)),
-            pl.BlockSpec(blk, lambda i, j: (i, j)),
-        ),
-        interpret=interpret,
-    )(e)
+    """One leaf → (bf16 payload, f32 residual) in one pass."""
+    return stream(_encode_bf16_kernel, (e,), (), (jnp.bfloat16, jnp.float32),
+                  interpret=interpret)

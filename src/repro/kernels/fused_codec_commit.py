@@ -25,20 +25,20 @@ The in-kernel arithmetic mirrors the reference chain cast for cast
 so the fused pull is bit-identical to decode → apply for f32 trees —
 the contract tests/test_update_rules.py pins per codec and shard count.
 
-Tiles are (32, 1024) like ``kernels.codec`` (int8 payloads participate;
-the int8 minimum sublane count is 32, a multiple of the f32/bf16
-minimums). The ops.py wrappers pad ragged tails, reshape, and carry the
-per-leaf scale / hyper-params as (1, n) operands broadcast to every
-block, exactly like ``fused_commit`` / ``codec``.
+Each call runs over one leaf in its own shape and layout, tiled by
+``kernels.tiling`` (row bands in multiples of 32 sublanes where an
+int8 payload takes part); the push side reads the update in its own dtype
+and widens it in-register. The ops.py wrappers carry the per-leaf scale
+/ hyper-params as (1, n) operands broadcast to every block, exactly like
+``fused_commit`` / ``codec``.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .codec import QBLOCK
+from .tiling import stream
 
 __all__ = [
     "quantize_int8_ef",
@@ -50,26 +50,13 @@ __all__ = [
 ]
 
 
-def _grid(x) -> tuple[int, int]:
-    r, c = x.shape
-    return (r // QBLOCK[0], c // QBLOCK[1])
-
-
-def _bspec():
-    return pl.BlockSpec(QBLOCK, lambda i, j: (i, j))
-
-
-def _hspec(n):
-    return pl.BlockSpec((1, n), lambda i, j: (0, 0))
-
-
 # ---------------------------------------------------------------------------
 # push side: error-feedback add fused into the encode pass
 # ---------------------------------------------------------------------------
 
 def _quantize_ef_kernel(u_ref, r_ref, s_ref, q_ref, ro_ref):
     scale = s_ref[0, 0]
-    e = u_ref[...] + r_ref[...]
+    e = u_ref[...].astype(jnp.float32) + r_ref[...]
     q = jnp.clip(jnp.round(e / scale), -127.0, 127.0)
     q_ref[...] = q.astype(jnp.int8)
     ro_ref[...] = e - q * scale
@@ -77,41 +64,24 @@ def _quantize_ef_kernel(u_ref, r_ref, s_ref, q_ref, ro_ref):
 
 def quantize_int8_ef(u: jax.Array, r: jax.Array, scale: jax.Array, *,
                      interpret: bool):
-    """(R, C) f32 update + residual → (int8 payload, next residual) with
-    the error-feedback add folded into the quantize pass."""
-    return pl.pallas_call(
-        _quantize_ef_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct(u.shape, jnp.int8),
-            jax.ShapeDtypeStruct(u.shape, jnp.float32),
-        ),
-        grid=_grid(u),
-        in_specs=[_bspec(), _bspec(), _hspec(1)],
-        out_specs=(_bspec(), _bspec()),
-        interpret=interpret,
-    )(u, r, scale)
+    """One leaf's update (any float dtype) + f32 residual → (int8
+    payload, next residual) with the error-feedback add folded into the
+    quantize pass."""
+    return stream(_quantize_ef_kernel, (u, r), (scale,), (jnp.int8, jnp.float32),
+                  interpret=interpret, updates=((1, 1),))
 
 
 def _encode_bf16_ef_kernel(u_ref, r_ref, q_ref, ro_ref):
-    e = u_ref[...] + r_ref[...]
+    e = u_ref[...].astype(jnp.float32) + r_ref[...]
     q = e.astype(jnp.bfloat16)
     q_ref[...] = q
     ro_ref[...] = e - q.astype(jnp.float32)
 
 
 def encode_bf16_ef(u: jax.Array, r: jax.Array, *, interpret: bool):
-    """(R, C) f32 update + residual → (bf16 payload, next residual)."""
-    return pl.pallas_call(
-        _encode_bf16_ef_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct(u.shape, jnp.bfloat16),
-            jax.ShapeDtypeStruct(u.shape, jnp.float32),
-        ),
-        grid=_grid(u),
-        in_specs=[_bspec(), _bspec()],
-        out_specs=(_bspec(), _bspec()),
-        interpret=interpret,
-    )(u, r)
+    """One leaf's update + f32 residual → (bf16 payload, next residual)."""
+    return stream(_encode_bf16_ef_kernel, (u, r), (), (jnp.bfloat16, jnp.float32),
+                  interpret=interpret, updates=((1, 1),))
 
 
 # ---------------------------------------------------------------------------
@@ -130,17 +100,9 @@ def _int8_apply_kernel(w_ref, d_ref, q_ref, s_ref, hp_ref, w_out, d_out):
 def int8_decode_apply(w, prev_delta, q, scale, hp, *, interpret: bool):
     """δ ← μ·δ − η·(q·s) ; W ← W + δ in one pass. ``hp`` is a (1, 2) f32
     [momentum, global_lr] operand; ``scale`` the per-leaf (1, 1) f32."""
-    return pl.pallas_call(
-        _int8_apply_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct(w.shape, w.dtype),
-            jax.ShapeDtypeStruct(w.shape, prev_delta.dtype),
-        ),
-        grid=_grid(w),
-        in_specs=[_bspec(), _bspec(), _bspec(), _hspec(1), _hspec(2)],
-        out_specs=(_bspec(), _bspec()),
-        interpret=interpret,
-    )(w, prev_delta, q, scale, hp)
+    return stream(_int8_apply_kernel, (w, prev_delta, q), (scale, hp),
+                  (w.dtype, prev_delta.dtype), interpret=interpret,
+                  updates=((0, 0), (1, 1)))
 
 
 def _bf16_apply_kernel(w_ref, d_ref, q_ref, hp_ref, w_out, d_out):
@@ -154,17 +116,9 @@ def _bf16_apply_kernel(w_ref, d_ref, q_ref, hp_ref, w_out, d_out):
 
 def bf16_decode_apply(w, prev_delta, q, hp, *, interpret: bool):
     """Same single pass with the bf16-payload decode (a widening cast)."""
-    return pl.pallas_call(
-        _bf16_apply_kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct(w.shape, w.dtype),
-            jax.ShapeDtypeStruct(w.shape, prev_delta.dtype),
-        ),
-        grid=_grid(w),
-        in_specs=[_bspec(), _bspec(), _bspec(), _hspec(2)],
-        out_specs=(_bspec(), _bspec()),
-        interpret=interpret,
-    )(w, prev_delta, q, hp)
+    return stream(_bf16_apply_kernel, (w, prev_delta, q), (hp,),
+                  (w.dtype, prev_delta.dtype), interpret=interpret,
+                  updates=((0, 0), (1, 1)))
 
 
 def _int8_accum_kernel(w_ref, q_ref, s_ref, hp_ref, w_out):
@@ -175,14 +129,8 @@ def _int8_accum_kernel(w_ref, q_ref, s_ref, hp_ref, w_out):
 
 def int8_decode_accum(w, q, scale, hp, *, interpret: bool):
     """Stateless plain-average pull: W ← W − η·(q·s) in one pass."""
-    return pl.pallas_call(
-        _int8_accum_kernel,
-        out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
-        grid=_grid(w),
-        in_specs=[_bspec(), _bspec(), _hspec(1), _hspec(1)],
-        out_specs=_bspec(),
-        interpret=interpret,
-    )(w, q, scale, hp)
+    return stream(_int8_accum_kernel, (w, q), (scale, hp), (w.dtype,),
+                  interpret=interpret, updates=((0, 0),))[0]
 
 
 def _bf16_accum_kernel(w_ref, q_ref, hp_ref, w_out):
@@ -193,11 +141,5 @@ def _bf16_accum_kernel(w_ref, q_ref, hp_ref, w_out):
 
 def bf16_decode_accum(w, q, hp, *, interpret: bool):
     """Stateless plain-average pull for bf16 payloads."""
-    return pl.pallas_call(
-        _bf16_accum_kernel,
-        out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
-        grid=_grid(w),
-        in_specs=[_bspec(), _bspec(), _hspec(1)],
-        out_specs=_bspec(),
-        interpret=interpret,
-    )(w, q, hp)
+    return stream(_bf16_accum_kernel, (w, q), (hp,), (w.dtype,),
+                  interpret=interpret, updates=((0, 0),))[0]

@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handles padding to block multiples, dtype plumbing, pytree dispatch for
-the commit ops, and the interpret-mode switch. ``_interp`` is the one
+Handles padding to block multiples for attention and the scans, pytree
+dispatch for the commit ops (each kernel runs over one leaf as it lies,
+``kernels.tiling``), and the interpret-mode switch. ``_interp`` is the one
 place it is resolved: ``interpret=None`` (the default) means interpret
 mode exactly when no TPU backend is present, so the same call sites run
 in the CPU container (validation) and natively on the chip; asking for
@@ -169,62 +170,23 @@ def rwkv6_scan(r, k, v, w, bonus, *, block_s=256, interpret=None):
 # ADSP commit ops over parameter pytrees
 # ---------------------------------------------------------------------------
 
-def _as_tiles(x, blk=None):
-    """Flatten to block-aligned 2-D (dtype-dependent sublane count, or an
-    explicit ``blk``); returns (tiled, orig_size). A leaf that is already
-    a tile-aligned 2-D buffer passes through untouched — no pad, no
-    reshape, no copy (tests pin this by object identity)."""
-    if blk is None:
-        blk = _fc.block_for(x.dtype)
-    n = x.size
-    cols = blk[1]
-    rows = -(-n // cols)
-    rows += (-rows) % blk[0]
-    if x.ndim == 2 and x.shape == (rows, cols):
-        return x, n
-    flat = x.reshape(-1)
-    total = rows * cols
-    if total != n:  # pad only ragged tails — aligned sizes skip the copy
-        flat = jnp.pad(flat, (0, total - n))
-    return flat.reshape(rows, cols), n
-
-
-def _from_tiles(t, n, shape, dtype):
-    if t.shape == tuple(shape) and t.dtype == jnp.dtype(dtype):
-        return t  # tile-aligned round trip: hand the buffer back as-is
-    return t.reshape(-1)[:n].reshape(shape).astype(dtype)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def accumulate_tree(u, g, local_lr, *, interpret=None):
-    """U ← U + η′·g leaf-wise via the fused Pallas kernel."""
+    """U ← U + η′·g leaf-wise via the fused Pallas kernel, one call per
+    leaf over the leaf as it lies (``kernels.tiling``)."""
     interp = _interp(interpret)
-
-    def per_leaf(ul, gl):
-        t, n = _as_tiles(ul)
-        gt, _ = _as_tiles(gl.astype(ul.dtype))
-        out = _fc.accumulate(t, gt, local_lr, interpret=interp)
-        return _from_tiles(out, n, ul.shape, ul.dtype)
-
-    return jax.tree.map(per_leaf, u, g)
+    return jax.tree.map(
+        lambda ul, gl: _fc.accumulate(ul, gl, local_lr, interpret=interp), u, g)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ps_apply_tree(w, prev_delta, u, global_lr, momentum, *, interpret=None):
     """W ← W + (μ·δ − η·U); returns (new_w, new_delta) pytrees."""
     interp = _interp(interpret)
-
-    def per_leaf(wl, dl, ul):
-        t, n = _as_tiles(wl)
-        dt, _ = _as_tiles(dl.astype(wl.dtype))
-        ut, _ = _as_tiles(ul.astype(wl.dtype))
-        nw, nd = _fc.ps_apply(t, dt, ut, global_lr, momentum, interpret=interp)
-        return (
-            _from_tiles(nw, n, wl.shape, wl.dtype),
-            _from_tiles(nd, n, wl.shape, wl.dtype),
-        )
-
-    pairs = jax.tree.map(per_leaf, w, prev_delta, u)
+    pairs = jax.tree.map(
+        lambda wl, dl, ul: _fc.ps_apply(wl, dl, ul, global_lr, momentum,
+                                        interpret=interp),
+        w, prev_delta, u)
     new_w = jax.tree.map(lambda p: p[0], pairs, is_leaf=lambda x: isinstance(x, tuple))
     new_d = jax.tree.map(lambda p: p[1], pairs, is_leaf=lambda x: isinstance(x, tuple))
     return new_w, new_d
@@ -234,41 +196,28 @@ def ps_apply_tree(w, prev_delta, u, global_lr, momentum, *, interpret=None):
 # transport codec passes (per-array; pytree dispatch lives in repro.transport)
 # ---------------------------------------------------------------------------
 
+def _s11(x):
+    return jnp.full((1, 1), x, jnp.float32)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quantize_int8(x, scale, *, interpret=None):
     """Symmetric int8 quantization of one array with a given positive
     scalar ``scale``: returns (q int8, error-feedback residual f32), both
     shaped like ``x``, out of a single fused HBM pass."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(x.astype(jnp.float32), _cd.QBLOCK)
-    s = jnp.full((1, 1), scale, jnp.float32)
-    q, r = _cd.quantize_int8(t, s, interpret=interp)
-    return (
-        _from_tiles(q, n, x.shape, jnp.int8),
-        _from_tiles(r, n, x.shape, jnp.float32),
-    )
+    return _cd.quantize_int8(x, _s11(scale), interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def dequantize_int8(q, scale, *, interpret=None):
     """PS-side decode of an int8 payload: q·scale as f32."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(q, _cd.QBLOCK)
-    s = jnp.full((1, 1), scale, jnp.float32)
-    out = _cd.dequantize_int8(t, s, interpret=interp)
-    return _from_tiles(out, n, q.shape, jnp.float32)
+    return _cd.dequantize_int8(q, _s11(scale), interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def encode_bf16(x, *, interpret=None):
     """bf16 cast of one array: (q bf16, residual f32) in a single pass."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(x.astype(jnp.float32), _cd.QBLOCK)
-    q, r = _cd.encode_bf16(t, interpret=interp)
-    return (
-        _from_tiles(q, n, x.shape, jnp.bfloat16),
-        _from_tiles(r, n, x.shape, jnp.float32),
-    )
+    return _cd.encode_bf16(x, interpret=_interp(interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -288,30 +237,16 @@ def quantize_int8_ef(u, r, scale, *, interpret=None):
     """Error-feedback int8 encode of one array in a single pass:
     e = u + r is formed in-register (never written to HBM), quantized
     with the given positive scalar ``scale``, and the next residual
-    e − q·scale comes out of the same pass."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(u.astype(jnp.float32), _cd.QBLOCK)
-    rt, _ = _as_tiles(r, _cd.QBLOCK)
-    s = jnp.full((1, 1), scale, jnp.float32)
-    q, res = _fcc.quantize_int8_ef(t, rt, s, interpret=interp)
-    return (
-        _from_tiles(q, n, u.shape, jnp.int8),
-        _from_tiles(res, n, u.shape, jnp.float32),
-    )
+    e − q·scale comes out of the same pass. ``u`` is read in its own
+    dtype and widened to f32 in-register."""
+    return _fcc.quantize_int8_ef(u, r, _s11(scale), interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def encode_bf16_ef(u, r, *, interpret=None):
     """Error-feedback bf16 encode: e = u + r cast and residualized in one
     pass, without materializing e."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(u.astype(jnp.float32), _cd.QBLOCK)
-    rt, _ = _as_tiles(r, _cd.QBLOCK)
-    q, res = _fcc.encode_bf16_ef(t, rt, interpret=interp)
-    return (
-        _from_tiles(q, n, u.shape, jnp.bfloat16),
-        _from_tiles(res, n, u.shape, jnp.float32),
-    )
+    return _fcc.encode_bf16_ef(u, r, interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -320,53 +255,28 @@ def int8_decode_apply(w, prev_delta, q, scale, global_lr, momentum, *,
     """Fused PS pull for an int8 payload: dequantize + Eqn. 1 apply in
     one pass. Returns (new_w, new_delta); arithmetic mirrors the
     reference decode → momentum_delta chain cast for cast."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(w, _cd.QBLOCK)
-    dt, _ = _as_tiles(prev_delta, _cd.QBLOCK)
-    qt, _ = _as_tiles(q, _cd.QBLOCK)
-    s = jnp.full((1, 1), scale, jnp.float32)
-    nw, nd = _fcc.int8_decode_apply(t, dt, qt, s, _hp2(momentum, global_lr),
-                                    interpret=interp)
-    return (
-        _from_tiles(nw, n, w.shape, w.dtype),
-        _from_tiles(nd, n, prev_delta.shape, prev_delta.dtype),
-    )
+    return _fcc.int8_decode_apply(w, prev_delta, q, _s11(scale),
+                                  _hp2(momentum, global_lr),
+                                  interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bf16_decode_apply(w, prev_delta, q, global_lr, momentum, *, interpret=None):
     """Fused PS pull for a bf16 payload: widening cast + Eqn. 1 apply."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(w, _cd.QBLOCK)
-    dt, _ = _as_tiles(prev_delta, _cd.QBLOCK)
-    qt, _ = _as_tiles(q, _cd.QBLOCK)
-    nw, nd = _fcc.bf16_decode_apply(t, dt, qt, _hp2(momentum, global_lr),
-                                    interpret=interp)
-    return (
-        _from_tiles(nw, n, w.shape, w.dtype),
-        _from_tiles(nd, n, prev_delta.shape, prev_delta.dtype),
-    )
+    return _fcc.bf16_decode_apply(w, prev_delta, q, _hp2(momentum, global_lr),
+                                  interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def int8_decode_accum(w, q, scale, global_lr, *, interpret=None):
     """Fused stateless pull (plain average) for an int8 payload:
     W ← W − η·(q·s) in one pass."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(w, _cd.QBLOCK)
-    qt, _ = _as_tiles(q, _cd.QBLOCK)
-    s = jnp.full((1, 1), scale, jnp.float32)
-    lr = jnp.full((1, 1), global_lr, jnp.float32)
-    nw = _fcc.int8_decode_accum(t, qt, s, lr, interpret=interp)
-    return _from_tiles(nw, n, w.shape, w.dtype)
+    return _fcc.int8_decode_accum(w, q, _s11(scale), _s11(global_lr),
+                                  interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bf16_decode_accum(w, q, global_lr, *, interpret=None):
     """Fused stateless pull (plain average) for a bf16 payload."""
-    interp = _interp(interpret)
-    t, n = _as_tiles(w, _cd.QBLOCK)
-    qt, _ = _as_tiles(q, _cd.QBLOCK)
-    lr = jnp.full((1, 1), global_lr, jnp.float32)
-    nw = _fcc.bf16_decode_accum(t, qt, lr, interpret=interp)
-    return _from_tiles(nw, n, w.shape, w.dtype)
+    return _fcc.bf16_decode_accum(w, q, _s11(global_lr),
+                                  interpret=_interp(interpret))

@@ -14,6 +14,9 @@ import numpy as np
 __all__ = [
     "fused_accumulate",
     "fused_ps_apply",
+    "quantize_int8",
+    "dequantize_int8",
+    "encode_bf16",
     "quantize_int8_ef",
     "encode_bf16_ef",
     "int8_decode_apply",
@@ -31,8 +34,9 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 def fused_accumulate(u: jax.Array, g: jax.Array, local_lr: float) -> jax.Array:
-    """U ← U + η′ · g   (worker-side accumulative update)."""
-    return u + local_lr * g
+    """U ← U + η′ · g   (worker-side accumulative update), in ``u``'s dtype."""
+    dt = u.dtype
+    return u + jnp.asarray(local_lr, jnp.float32).astype(dt) * g.astype(dt)
 
 
 def fused_ps_apply(
@@ -43,9 +47,35 @@ def fused_ps_apply(
     momentum: float,
 ) -> tuple[jax.Array, jax.Array]:
     """PS update with explicit momentum (Eqn. 1, μ possibly reduced by the
-    implicit-momentum correction): δ ← μ·δ_prev − η·U ; W ← W + δ."""
-    delta = momentum * prev_delta - global_lr * u
+    implicit-momentum correction): δ ← μ·δ_prev − η·U ; W ← W + δ, all
+    in ``w``'s dtype."""
+    dt = w.dtype
+    mu, lr = (jnp.asarray(x, jnp.float32).astype(dt) for x in (momentum, global_lr))
+    delta = mu * prev_delta.astype(dt) - lr * u.astype(dt)
     return w + delta, delta
+
+
+# ---------------------------------------------------------------------------
+# Codec passes (DESIGN.md §10): the per-leaf encode / decode kernels
+# ---------------------------------------------------------------------------
+
+def quantize_int8(x, scale):
+    """Symmetric int8 quantize of e = f32(x) and its residual e − q·s."""
+    e = x.astype(jnp.float32)
+    q = jnp.clip(jnp.round(e / scale), -127.0, 127.0)
+    return q.astype(jnp.int8), e - q * scale
+
+
+def dequantize_int8(q, scale):
+    """PS-side int8 decode: q·s as f32."""
+    return q.astype(jnp.float32) * scale
+
+
+def encode_bf16(x):
+    """bf16 payload of e = f32(x) and its residual e − f32(q)."""
+    e = x.astype(jnp.float32)
+    q = e.astype(jnp.bfloat16)
+    return q, e - q.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,9 @@ import: only one process may load the TPU library, and every test worker
 imports every test file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +76,7 @@ def _cases(sh):
             _spec(sh, (1, lru["s"], lru["w"]), f32),
         )),
         "quantize_int8_ef": (ops.quantize_int8_ef, (
-            _spec(sh, m, f32), _spec(sh, m, f32), scalar)),
+            _spec(sh, m, bf16), _spec(sh, m, f32), scalar)),
         "encode_bf16_ef": (ops.encode_bf16_ef, (
             _spec(sh, m, f32), _spec(sh, m, f32))),
         "int8_decode_apply": (ops.int8_decode_apply, (
@@ -100,3 +102,72 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _cases(one_chip)[name]
     hlo = _compile(fn, *args).as_text()
     assert "tpu_custom_call" in hlo, f"{name}: the Pallas call did not survive"
+
+
+# ---------------------------------------------------------------------------
+# the ADSP update kernels over the training cell's whole leaf tree: each
+# call is built over the leaf as it lies and writes the state it updates
+# over the donated buffer, so nothing but the kernels and bitcasts may
+# touch a leaf-sized buffer
+# ---------------------------------------------------------------------------
+
+# granite3-8b-l3's 12 leaves as the program stacks them (tests/
+# test_leaf_tiling.py checks them against the model), and one of four
+# shards of an MLP leaf as ``make_sharded_apply`` hands it over
+CELL_LEAVES = [(49408, 4096), (4096,), (3, 4096, 8, 128), (3, 4096, 4096),
+               (3, 4096, 32, 128), (3, 4096, 8, 128), (3, 4096, 12800),
+               (3, 4096, 12800), (3, 12800, 4096), (3, 4096), (3, 4096),
+               (4096, 49408), (3, 4096, 3200)]
+_BYTES = {"bf16": 2, "f32": 4, "s8": 1, "u8": 1, "pred": 1, "s32": 4, "u32": 4}
+_LEAF_OPS = {"parameter", "custom-call", "bitcast", "get-tuple-element", "tuple"}
+
+
+def _tree_cases(sh):
+    def leaves(dtype):
+        return [_spec(sh, s, dtype) for s in CELL_LEAVES]
+
+    # the state a kernel updates is donated, as the train step donates it,
+    # and comes back in its own order (jit pairs donated buffers with
+    # outputs by shape, in order)
+    scalar = _spec(sh, (), "float32")
+    return {
+        "accumulate_tree": (
+            jax.jit(lambda u, g, lr: ops.accumulate_tree(u, g, lr, interpret=False),
+                    donate_argnums=0),
+            (leaves("bfloat16"), leaves("bfloat16"), scalar)),
+        "quantize_int8_ef": (
+            jax.jit(lambda us, rs, s: [ops.quantize_int8_ef(u, r, s, interpret=False)
+                                       for u, r in zip(us, rs)], donate_argnums=1),
+            (leaves("bfloat16"), leaves("float32"), scalar)),
+        "int8_decode_apply": (  # (new ws, new ds): each takes its own buffer
+            jax.jit(lambda ws, ds, qs, s, lr, mu: tuple(zip(*[
+                ops.int8_decode_apply(w, d, q, s, lr, mu, interpret=False)
+                for w, d, q in zip(ws, ds, qs)])), donate_argnums=(0, 1)),
+            (leaves("bfloat16"), leaves("bfloat16"), leaves("int8"),
+             scalar, scalar, scalar)),
+    }
+
+
+def _large_non_kernel_ops(hlo: str, floor: int = 1 << 20) -> list[str]:
+    """Instructions of the entry computation whose result holds at least
+    ``floor`` bytes and that are neither a kernel nor a bitcast."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}")]
+    bad = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9-]*)\(", line)
+        if not m or m.group(2) in _LEAF_OPS:
+            continue
+        size = sum(_BYTES.get(t, 4) * math.prod(int(n) for n in dims.split(",") if n)
+                   for t, dims in re.findall(r"([a-z]+\d*)\[([\d,]*)\]", m.group(1)))
+        if size >= floor:
+            bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.mark.parametrize("name", ["accumulate_tree", "quantize_int8_ef", "int8_decode_apply"])
+def test_update_kernels_take_leaves_as_they_lie(one_chip, name):
+    fn, args = _tree_cases(one_chip)[name]
+    hlo = fn.lower(*args).compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == len(CELL_LEAVES)
+    assert _large_non_kernel_ops(hlo) == []

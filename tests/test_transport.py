@@ -195,29 +195,6 @@ def test_fused_codec_commit_kernel_ops_match_ref(shape):
                        np.asarray(ref.bf16_decode_accum(w, qb, lr)))
 
 
-def test_as_tiles_skips_copy_for_aligned_leaves():
-    """A leaf whose size is already a tile multiple passes through
-    _as_tiles/_from_tiles untouched — the same buffer, no pad/reshape
-    copy — while ragged leaves still take the padded path."""
-    from repro.kernels import ops
-    from repro.kernels.codec import QBLOCK
-
-    x = jnp.ones(QBLOCK, jnp.float32)  # exactly one tile
-    t, n = ops._as_tiles(x, QBLOCK)
-    assert t is x and n == x.size
-    assert ops._from_tiles(t, n, x.shape, x.dtype) is t
-
-    big = jnp.ones((4 * QBLOCK[0], QBLOCK[1]), jnp.float32)
-    t, _ = ops._as_tiles(big, QBLOCK)
-    assert t is big
-
-    ragged = jnp.ones((257,), jnp.float32)
-    t, n = ops._as_tiles(ragged, QBLOCK)
-    assert t is not ragged and t.shape == QBLOCK and n == 257
-    back = ops._from_tiles(t, n, ragged.shape, ragged.dtype)
-    assert_array_equal(np.asarray(back), np.asarray(ragged))
-
-
 def test_overlapped_shard_pulls_donate_param_buffers():
     """The overlapped commit's per-shard pull jits carry
     donate_argnums=(0, 1): each shard's params and commit state are dead
